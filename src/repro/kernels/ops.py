@@ -12,6 +12,7 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend import core as jcore
 
 from repro.kernels import ref
 from repro.kernels.quant import dequantize_rows as _dequantize_rows
@@ -97,11 +98,6 @@ def iter_jaxpr_eqns(jaxpr):
     wrappers AND ``shard_map``.  Shared by ``count_pallas_calls`` (kernel
     launch contracts) and ``dist/exchange.py::measured_exchange_bytes``
     (collective-traffic accounting against the analytic bytes models)."""
-    try:  # jax >= 0.5 moved the jaxpr types; 0.4.x only has jax.core
-        from jax.extend import core as jcore
-    except ImportError:  # pragma: no cover
-        from jax import core as jcore
-
     def subjaxprs(params):
         for v in params.values():
             vs = v if isinstance(v, (tuple, list)) else (v,)
@@ -148,11 +144,6 @@ def max_intermediate_bytes(fn, *args, **kwargs) -> int:
     live buffer must not grow with the chunk count, while the one-shot
     encoder's grows linearly with the segment count.
     """
-    try:  # jax >= 0.5 moved the jaxpr types; 0.4.x only has jax.core
-        from jax.extend import core as jcore
-    except ImportError:  # pragma: no cover
-        from jax import core as jcore
-
     def subjaxprs(params):
         for v in params.values():
             vs = v if isinstance(v, (tuple, list)) else (v,)

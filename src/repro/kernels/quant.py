@@ -71,8 +71,11 @@ def _bf16_stochastic(x, bits):
 
 
 def _uniform01(bits):
-    """uint32 -> uniform [0, 1) f32 from the high 24 bits."""
-    return (bits >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
+    """uint32 -> uniform [0, 1) f32 from the high 24 bits.  ``bits >> 8``
+    fits in 24 bits, so the int32 hop is exact; Mosaic has no direct
+    uint32 -> f32 cast."""
+    hi24 = (bits >> 8).astype(jnp.int32)
+    return hi24.astype(jnp.float32) * (1.0 / (1 << 24))
 
 
 def _int8_quantize(x, bits):

@@ -52,12 +52,12 @@ DEFAULT_N_BLK = 8
 def _spmm_batched_kernel(src_ref, dst_ref, w_ref, h_ref, out_ref, *,
                          m: int, n_blk: int):
     eb = pl.program_id(2)                  # edge-block = innermost (reduction)
-    e_blk = src_ref.shape[1]
+    e_blk = src_ref.shape[2]
     node_ids = jax.lax.broadcasted_iota(jnp.int32, (e_blk, m), 1)
     for i in range(n_blk):                 # static unroll over the seg block
-        src = src_ref[i, :]                # (e_blk,)
-        dst = dst_ref[i, :]
-        w = w_ref[i, :]                    # (e_blk,) float, 0 on padding
+        src = src_ref[i, 0, :]             # (e_blk,)
+        dst = dst_ref[i, 0, :]
+        w = w_ref[i, 0, :]                 # (e_blk,) float, 0 on padding
         h = h_ref[i]                       # (m, d_blk)
         gather = (src[:, None] == node_ids).astype(h.dtype)     # (e_blk, m)
         scatter = (dst[:, None] == node_ids).astype(h.dtype)    # (e_blk, m)
@@ -109,13 +109,16 @@ def _spmm_batched_raw(h, src, dst, w, e_blk: int, d_blk: int, n_blk,
         dst = jnp.pad(dst, ((0, pad_n), (0, 0)))
         w = jnp.pad(w, ((0, pad_n), (0, 0)))
     grid = ((N + pad_n) // n_blk, (d + pad_d) // d_blk, (e + pad_e) // e_blk)
+    # the edge arrays get a unit middle axis so their block's last two dims,
+    # (1, e_blk), meet the TPU tiling rule (divisible by (8, 128) or equal
+    # to the array's) for every n_blk the VMEM budget picks
+    src, dst, w = (a[:, None, :] for a in (src, dst, w))
+    edge_spec = pl.BlockSpec((n_blk, 1, e_blk), lambda n, db, eb: (n, 0, eb))
     out = pl.pallas_call(
         functools.partial(_spmm_batched_kernel, m=m, n_blk=n_blk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((n_blk, e_blk), lambda n, db, eb: (n, eb)),
-            pl.BlockSpec((n_blk, e_blk), lambda n, db, eb: (n, eb)),
-            pl.BlockSpec((n_blk, e_blk), lambda n, db, eb: (n, eb)),
+            edge_spec, edge_spec, edge_spec,
             pl.BlockSpec((n_blk, m, d_blk), lambda n, db, eb: (n, 0, db)),
         ],
         out_specs=pl.BlockSpec((n_blk, m, d_blk), lambda n, db, eb: (n, 0, db)),

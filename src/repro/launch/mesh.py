@@ -12,15 +12,6 @@ from __future__ import annotations
 import jax
 
 
-def _mesh_kwargs(n_axes: int) -> dict:
-    """axis_types only where this jax has it (jax.sharding.AxisType landed
-    after 0.4.x; older versions default to Auto semantics anyway)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
@@ -34,13 +25,13 @@ def make_production_mesh(*, multi_pod: bool = False):
             "dry-run entrypoint must set XLA_FLAGS=--xla_force_host_platform_"
             "device_count=512 before importing jax (launch/dryrun.py does).")
     return jax.make_mesh(shape, axes, devices=devs[:need],
-                         **_mesh_kwargs(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh():
     """1-device mesh with the production axis names (CPU tests)."""
     return jax.make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1],
-                         **_mesh_kwargs(2))
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def fsdp_axes(mesh) -> tuple:

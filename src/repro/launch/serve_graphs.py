@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def build_engine(args):
     from repro.serve import ServeConfig, ServeEngine
@@ -65,6 +67,7 @@ def check_parity(engine, graphs, atol: float) -> float:
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--unique", type=int, default=24)
@@ -113,6 +116,9 @@ def main(argv=None):
     from repro.obs import Obs, add_obs_args
     add_obs_args(ap)
     args = ap.parse_args(argv)
+    if args.use_pallas and args.backbone == "gps":
+        print("[serve_graphs] --use-pallas: gps has no fused kernel; "
+              "encoding on the jnp reference path")
 
     from repro.serve import TrafficConfig, make_request_stream
 

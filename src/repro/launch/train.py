@@ -26,6 +26,7 @@ from repro.checkpoint import save_checkpoint
 from repro.configs import get_config, reduced as reduce_cfg
 from repro.core import gst as G
 from repro.data.tokens import doc_batch_iterator, make_lm_stream, make_property_docs
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.obs import Obs, StalenessProbe, add_obs_args
 from repro.obs.trace import span
@@ -35,6 +36,9 @@ from repro.store import DeviceStore, TieredStore
 
 def train_graph(args, obs):
     from repro.graphs.experiment import run_experiment
+    if args.use_pallas and args.backbone == "gps":
+        print("[graph] --use-pallas: gps has no fused kernel; encoding on "
+              "the jnp reference path")
     r = run_experiment(
         dataset=args.dataset, backbone=args.backbone, variant=args.variant,
         n_graphs=args.n_graphs, epochs=args.epochs,
@@ -178,6 +182,7 @@ def train_lm(args):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--track", default="graph", choices=["graph", "seq", "lm"])
     # graph track

@@ -42,6 +42,9 @@ def _force_device_count(n: int) -> None:
 
 
 def main(argv=None):
+    """Train, print per-epoch progress, and return ``{"epoch_losses":
+    [last train loss of each epoch], "finetune_loss": float | None,
+    "metric": final eval metric, "state": final TrainState}``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", type=int, default=None,
                     help="data-parallel width (forces an N-device host on "
@@ -139,6 +142,9 @@ def main(argv=None):
 
     if args.devices:
         _force_device_count(args.devices)
+    # first jax import of the CLI: it must follow the device-count override
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     import jax
     import jax.numpy as jnp
@@ -412,6 +418,7 @@ def main(argv=None):
 
         t_start = time.perf_counter()
         last_stats = None
+        epoch_losses = []
         run_epoch = (run_epoch_prefetch if args.prefetch_lookups
                      else run_epoch_inline)
         for epoch, sched in enumerate(train_scheds):
@@ -420,8 +427,8 @@ def main(argv=None):
                 put_pinned if args.prefetch_lookups else put,
                 depth=args.depth)
             losses, last_stats = run_epoch(epoch, feeder)
-            jax.block_until_ready(losses[-1])
-            print(f"epoch {epoch}: loss={float(losses[-1]):.4f} "
+            epoch_losses.append(float(losses[-1]))
+            print(f"epoch {epoch}: loss={epoch_losses[-1]:.4f} "
                   f"host_blocked={last_stats.host_blocked_ms_per_batch:.2f} "
                   f"ms/batch", flush=True)
             # resident rows rewritten this epoch re-report their true
@@ -445,6 +452,7 @@ def main(argv=None):
                       f"sed-drop {stale['sed_drop_rate']:.3f}", flush=True)
         print_store_line()
 
+        finetune_loss = None
         if var.finetune_head:
             refresh = DT.make_dist_refresh_step(enc, ctx=ctx)
             for prep, batch in DP.make_feeder("sync", ds, refresh_sched, put):
@@ -463,7 +471,8 @@ def main(argv=None):
                     state = state._replace(table=store.commit(state.table, prep))
                     state, m = ft(state, batch)
             if m is not None:
-                print(f"finetune: loss={float(m['loss']):.4f}")
+                finetune_loss = float(m["loss"])
+                print(f"finetune: loss={finetune_loss:.4f}")
 
         # eval never reads the table — no store routing (a begun-but-uncommitted
         # migration would corrupt residency bookkeeping)
@@ -474,18 +483,21 @@ def main(argv=None):
         # surface any failed async write-back BEFORE reporting success
         store.flush_writebacks()
         wall = time.perf_counter() - t_start
+        metric = float(np.mean(metrics))
         print(f"[dist] done in {wall:.1f}s — train metric "
-              f"{float(np.mean(metrics)):.3f}, host blocked "
+              f"{metric:.3f}, host blocked "
               f"{last_stats.host_blocked_ms_per_batch:.2f} ms/batch "
               f"({args.feeder})")
         print_store_line()
         if obs.enabled:
             store.publish_counters()
             probe.observe_store_counters(store.counters.as_dict())
-        obs.close(wall_s=wall, train_metric=float(np.mean(metrics)))
+        obs.close(wall_s=wall, train_metric=metric)
     finally:
         store.close()   # stop the write-back thread even on error
         obs.close()
+    return {"epoch_losses": epoch_losses, "finetune_loss": finetune_loss,
+            "metric": metric, "state": state}
 
 
 if __name__ == "__main__":

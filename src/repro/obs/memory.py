@@ -28,10 +28,11 @@ bytes render as a timeline counter track.  Host-side byte tracking
 (tiered-store host tier, feeder staging buffers) goes through
 :meth:`MemoryProbe.observe_host` → ``mem.host.<site>_bytes`` gauges.
 
-On backends / jax versions where ``memory_analysis`` is unavailable the
-shared extraction helper (``roofline/analysis.py``) returns ``None`` and
-the probe degrades to accounting-only: the record carries the jaxpr-walk
-``max_intermediate_bytes`` lower bound instead of compiled stats.
+Off the TPU, where ``memory_analysis`` may be unavailable, the shared
+extraction helper (``roofline/analysis.py``) returns ``None`` and the
+probe degrades to accounting-only: the record carries the jaxpr-walk
+``max_intermediate_bytes`` lower bound instead of compiled stats.  On the
+TPU a failed compile or a missing ``memory_analysis`` raises instead.
 
 Like the registry and tracer, the probe is a process-wide global
 defaulting to :class:`NullProbe`; instrumented call sites use
@@ -138,12 +139,19 @@ class MemoryProbe:
         self._publish(site, rec)
 
     def _measure(self, jitted, args, kwargs) -> Dict[str, Any]:
+        import jax
+
         from repro.roofline.analysis import (compiled_cost_stats,
                                              compiled_memory_stats,
                                              device_peak_bytes)
+        # on the chip a failed compile or missing memory stats is a fault
+        # to surface, never a record to degrade into
+        on_tpu = jax.default_backend() == "tpu"
         try:
             compiled = jitted.lower(*args, **kwargs).compile()
         except Exception as e:
+            if on_tpu:
+                raise
             return {"mode": "error", "error": str(e)}
         mem = compiled_memory_stats(compiled)
         cost = compiled_cost_stats(compiled)
@@ -153,6 +161,9 @@ class MemoryProbe:
                        peak_bytes=device_peak_bytes(mem),
                        temp_bytes=mem.get("temp_size_in_bytes", 0))
             return out
+        if on_tpu:
+            raise RuntimeError("memory_analysis() reported nothing for a "
+                               "program compiled on the TPU")
         # accounting-only degrade: the jaxpr-walk largest-intermediate
         # bound stands in for the unavailable compiled temp stats
         out["mode"] = "accounting"

@@ -361,6 +361,19 @@ def test_probe_survives_uncompilable_entry_point():
     assert rec["mode"] == "error"
 
 
+def test_probe_raises_on_tpu_instead_of_degrading(monkeypatch):
+    class _Boom(_NoMemJit):
+        def lower(self, *a, **k):
+            raise RuntimeError("no lowering for you")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    set_probe(MemoryProbe())
+    with pytest.raises(RuntimeError, match="no lowering"):
+        probe_jit("t.boom", _Boom())(jnp.ones((2,)))
+    with pytest.raises(RuntimeError, match="memory_analysis"):
+        probe_jit("t.nomem", _NoMemJit())(jnp.ones((2,)))
+
+
 # ---------------------------------------------------------------------------
 # the memory gate
 # ---------------------------------------------------------------------------

@@ -13,7 +13,7 @@ import sys
 
 import numpy as np
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.graphs.partition import (PARTITIONERS, bfs_partition,
                                     louvain_partition, partition_graph)
 

@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.ops import dequantize_payload, quantize_payload
 from repro.kernels.quant import (PAYLOAD_DTYPES, dequantize_rows,

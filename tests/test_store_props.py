@@ -1,8 +1,7 @@
 """Property tests for the tiered embedding store's tier invariants.
 
-Random (geometry, batch-sequence) draws — via hypothesis when installed,
-the deterministic fallback otherwise (tests/_hypothesis_compat.py) —
-checked after EVERY prepare/update against a dense oracle table:
+Random (geometry, batch-sequence) draws from hypothesis, checked after
+EVERY prepare/update against a dense oracle table:
 
   * device-tier occupancy never exceeds the per-shard capacity, and no
     two keys ever share a slot (SlotMap internal consistency);
@@ -20,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import embedding_table as tbl
 from repro.store import SlotMap, TieredStore
