@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.graphs.data import SyntheticGraph
 from repro.graphs.partition import partition_graph
+from repro.obs.trace import span
 
 
 @dataclass
@@ -115,9 +116,14 @@ def batch_id_schedule(n: int, batch_size: int, *, rng: np.random.Generator,
 
 def batch_iterator(ds: SegmentedDataset, batch_size: int, *, rng: np.random.Generator,
                    shuffle: bool = True) -> Iterator[Tuple[Dict, np.ndarray, np.ndarray, np.ndarray]]:
-    """Yields (seg_inputs, seg_valid, graph_ids, labels) batches (drop-last)."""
+    """Yields (seg_inputs, seg_valid, graph_ids, labels) batches (drop-last).
+    Each batch's host gather is one ``feeder.assemble`` span, closed before
+    the yield so that it never covers the consumer's work."""
     for ids in batch_id_schedule(ds.n, batch_size, rng=rng, shuffle=shuffle):
-        yield ds.seg_inputs(ids), ds.seg_valid[ids], ids.astype(np.int32), ds.labels[ids]
+        with span("feeder.assemble", batch=len(ids)):
+            tup = (ds.seg_inputs(ids), ds.seg_valid[ids],
+                   ids.astype(np.int32), ds.labels[ids])
+        yield tup
 
 
 def whole_graph_dataset(graphs: List[SyntheticGraph]) -> SegmentedDataset:

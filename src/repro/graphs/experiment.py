@@ -39,9 +39,23 @@ class ExperimentResult:
 
 
 def _to_batch(seg_inputs, seg_valid, ids, labels) -> G.GSTBatch:
-    return G.GSTBatch(
-        {k: jnp.asarray(v) for k, v in seg_inputs.items()},
-        jnp.asarray(seg_valid), jnp.asarray(ids), jnp.asarray(labels))
+    """The batch's host arrays put on the device, as one ``feeder.put``
+    span."""
+    with span("feeder.put"):
+        return G.GSTBatch(
+            {k: jnp.asarray(v) for k, v in seg_inputs.items()},
+            jnp.asarray(seg_valid), jnp.asarray(ids), jnp.asarray(labels))
+
+
+def run_step(step, state: G.TrainState, batch: G.GSTBatch, key):
+    """One training step of the jitted ``step``: its dispatch under a
+    ``train.step`` span, then the wait for its loss under a child
+    ``train.wait``.  Returns ``(state, metrics)`` with the loss ready."""
+    with span("train.step"):
+        state, m = step(state, batch, key)
+        with span("train.wait"):
+            jax.block_until_ready(m["loss"])
+    return state, m
 
 
 def run_experiment(
@@ -179,10 +193,9 @@ def run_experiment(
                 # replaces state.table before the step sees it; the hint is
                 # the step about to WRITE these rows
                 slots = route(tup, step=step_counter["t"])
-                with span("train.step", epoch=epoch):
-                    state, m = step(state, batch._replace(graph_ids=slots),
+                state, m = run_step(step, state,
+                                    batch._replace(graph_ids=slots),
                                     jax.random.key(epoch))
-                    jax.block_until_ready(m["loss"])
                 step_counter["t"] += 1
                 iter_times.append(time.perf_counter() - t0)
                 ep_metrics.append(float(m["metric"]))

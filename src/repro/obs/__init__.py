@@ -16,7 +16,7 @@ from repro.obs.metrics import (AGE_BUCKETS_STEPS, BYTES_BUCKETS, Counter,
                                get_registry, null_registry, set_registry,
                                summarize)
 from repro.obs.trace import (NullTracer, Tracer, counter, get_tracer,
-                             instant, null_tracer, set_tracer, span,
+                             null_tracer, set_tracer, span,
                              validate_chrome_trace)
 from repro.obs.memory import (MemoryProbe, NullProbe, get_probe, null_probe,
                               probe_jit, process_rss_bytes, set_probe,
@@ -32,7 +32,7 @@ __all__ = [
     "MetricsRegistry", "NullRegistry",
     "dict_delta", "enable_metrics", "exponential_buckets",
     "get_registry", "null_registry", "set_registry", "summarize",
-    "NullTracer", "Tracer", "counter", "get_tracer", "instant",
+    "NullTracer", "Tracer", "counter", "get_tracer",
     "null_tracer", "set_tracer", "span", "validate_chrome_trace",
     "MemoryProbe", "NullProbe", "get_probe", "null_probe", "probe_jit",
     "process_rss_bytes", "set_probe", "shape_signature", "tree_nbytes",

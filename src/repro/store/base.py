@@ -40,6 +40,7 @@ import numpy as np
 from repro.core import embedding_table as tbl
 from repro.kernels.ops import pad_leading, pad_rows_pow2
 from repro.obs.metrics import get_registry
+from repro.obs.trace import span
 
 
 # -- block row partition (canonical home; dist/table.py re-exports) ---------
@@ -218,10 +219,12 @@ class EmbeddingStore:
     def prepare(self, table: tbl.EmbeddingTable, row_ids, *,
                 fetch: bool = True, step: Optional[int] = None,
                 ) -> Tuple[tbl.EmbeddingTable, np.ndarray]:
-        """begin + commit in one call (synchronous drivers).  ``step``:
-        refresh hint for stale-first eviction (see TieredStore.begin)."""
-        prep = self.begin(row_ids, fetch=fetch, step=step)
-        return self.commit(table, prep), prep.slots
+        """begin + commit in one call (synchronous drivers), as one
+        ``store.prepare`` span.  ``step``: refresh hint for stale-first
+        eviction (see TieredStore.begin)."""
+        with span("store.prepare"):
+            prep = self.begin(row_ids, fetch=fetch, step=step)
+            return self.commit(table, prep), prep.slots
 
     def release(self, prep: PreparedMigration) -> None:
         """Drop the residency pins ``begin(pin=True)`` took for this

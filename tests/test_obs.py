@@ -20,6 +20,7 @@
 """
 import json
 import threading
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -225,7 +226,6 @@ def test_tracer_multithreaded_export_is_valid_chrome_trace(tmp_path):
     with tr.span("train.step", epoch=0):
         with tr.span("store.commit"):
             pass
-    tr.instant("epoch.end", epoch=0)
     for t in threads:
         t.join()
 
@@ -241,6 +241,106 @@ def test_tracer_multithreaded_export_is_valid_chrome_trace(tmp_path):
     assert all(e["dur"] >= 1 for e in xs)
     # spans from 4 distinct threads landed in one stream
     assert len({e["tid"] for e in xs}) == 4
+
+
+def test_span_parents_across_nesting_and_threads():
+    """A span's parent is the span open on its own thread when it began;
+    another thread's open spans are not its parents."""
+    tr = Tracer()
+    set_tracer(tr)
+    inside = threading.Event()
+    release = threading.Event()
+
+    def worker():
+        with tr.span("feeder.assemble"):
+            inside.set()
+            release.wait(timeout=10)
+            with tr.span("feeder.put"):
+                pass
+    t = threading.Thread(target=worker)
+    with tr.span("train.step"):
+        t.start()
+        assert inside.wait(timeout=10)
+        with tr.span("train.wait"):
+            release.set()
+            t.join(timeout=10)
+    assert not t.is_alive()
+    with tr.span("store.prepare"):
+        pass
+    parents = {name: parent for _, _, name, parent in tr.spans()}
+    assert parents == {"feeder.put": "feeder.assemble",
+                       "feeder.assemble": None, "train.wait": "train.step",
+                       "train.step": None, "store.prepare": None}
+
+
+def test_spans_on_perf_counter_seconds():
+    import time
+    tr = Tracer()
+    set_tracer(tr)
+    t0 = time.perf_counter()
+    with tr.span("outer"):
+        time.sleep(0.01)
+        t_mid = time.perf_counter()
+        with tr.span("inner"):
+            pass
+    t1 = time.perf_counter()
+    (si, ei, ni, pi), (so, eo, no, po) = tr.spans()   # in order of ending
+    assert (ni, pi, no, po) == ("inner", "outer", "outer", None)
+    assert t0 <= so <= t_mid <= si <= ei <= eo <= t1
+    assert eo - so >= 0.01
+
+
+def test_export_records_its_clock(tmp_path):
+    """``ts`` counts microseconds from ``otherData``'s epoch on the named
+    clock, so an exported span lands where ``spans()`` puts it."""
+    tr = Tracer()
+    set_tracer(tr)
+    with tr.span("train.step"):
+        pass
+    payload = json.loads(Path(tr.export(str(tmp_path / "t.json")))
+                         .read_text())
+    assert validate_chrome_trace(payload) == []
+    other = payload["otherData"]
+    assert other["clock"] == "time.perf_counter"
+    (ev,) = [e for e in payload["traceEvents"] if e["ph"] == "X"]
+    ((s, e, _, _),) = tr.spans()
+    assert other["epoch_s"] + ev["ts"] * 1e-6 == pytest.approx(s, abs=2e-6)
+    assert ev["dur"] * 1e-6 == pytest.approx(e - s, abs=2e-6)
+
+
+def test_compile_is_a_span_under_the_open_span():
+    tr = Tracer()
+    set_tracer(tr)
+    f = jax.jit(lambda x: x * 3.0 + 1.0)
+    x = np.ones((3, 5), np.float32)       # host inputs: nothing else compiles
+    with tr.span("train.step"):
+        f(x).block_until_ready()
+    f(x).block_until_ready()                       # cached: no compile
+    compiles = [s for s in tr.spans() if s[2] == "jit.compile"]
+    assert len(compiles) == 1 and compiles[0][3] == "train.step"
+    (step,) = [s for s in tr.spans() if s[2] == "train.step"]
+    assert step[0] <= compiles[0][0] <= compiles[0][1] <= step[1]
+    (ev,) = [e for e in tr.events() if e["name"] == "jit.compile"]
+    assert "<lambda>" in ev["args"]["fun_name"]
+    f(np.ones((4, 5), np.float32)).block_until_ready()   # a new shape
+    assert sum(s[2] == "jit.compile" for s in tr.spans()) == 2
+
+
+def test_null_tracer_records_nothing(dataset):
+    """Under the NullTracer the program's spans and compiles go nowhere,
+    not even to a tracer installed earlier."""
+    from repro.graphs import batching as Bt
+    from repro.graphs import experiment as EX
+    old = Tracer()
+    set_tracer(old)
+    set_tracer(null_tracer())
+    enc, opt, state = _state(dataset)
+    step = jax.jit(G.make_train_step(enc, opt, G.VARIANTS["gst_efd"],
+                                     keep_prob=0.5))
+    tup = next(Bt.batch_iterator(dataset, 4, rng=np.random.default_rng(0)))
+    EX.run_step(step, state, EX._to_batch(*tup), jax.random.key(0))
+    assert len(old) == 0 and old.spans() == []
+    assert null_tracer().spans() == [] and len(null_tracer()) == 0
 
 
 def test_null_tracer_refuses_export():
