@@ -39,12 +39,42 @@ class ExperimentResult:
 
 
 def _to_batch(seg_inputs, seg_valid, ids, labels) -> G.GSTBatch:
-    """The batch's host arrays put on the device, as one ``feeder.put``
-    span."""
+    """The batch put on the device, as one ``feeder.put`` span; arrays
+    already there (a resident gather) are not copied."""
     with span("feeder.put"):
         return G.GSTBatch(
             {k: jnp.asarray(v) for k, v in seg_inputs.items()},
             jnp.asarray(seg_valid), jnp.asarray(ids), jnp.asarray(labels))
+
+
+def _step_temp_bytes(step, state: G.TrainState, batch: G.GSTBatch,
+                     key) -> int:
+    """Temp bytes of ``step`` compiled for these arguments (which may be
+    shapes)."""
+    compiled = step.lower(state, batch, key).compile()
+    return int(compiled.memory_analysis().temp_size_in_bytes)
+
+
+def _place_datasets(step, state: G.TrainState, datasets,
+                    batch_size: int) -> None:
+    """Decides the feeder's residency (``SegmentedDataset.resident``)
+    where the training step is known: before its first batch, each
+    dataset reserves the compiled step's temp bytes, one gathered batch
+    and the bytes of the datasets after it.  A backend that reports no
+    memory places them without compiling."""
+    if Bt._device_free_bytes() is None:
+        return
+    ds = datasets[0]
+    shape = lambda a: jax.ShapeDtypeStruct((batch_size,) + a.shape[1:],
+                                           a.dtype)
+    batch = G.GSTBatch({k: shape(getattr(ds, k)) for k in Bt.SEG_FIELDS},
+                       shape(ds.seg_valid),
+                       jax.ShapeDtypeStruct((batch_size,), jnp.int32),
+                       shape(ds.labels))
+    reserve = (_step_temp_bytes(step, state, batch, jax.random.key(0))
+               + batch_size * ds.seg_nbytes // ds.n)
+    for i, d in enumerate(datasets):
+        d.resident(reserve + sum(e.seg_nbytes for e in datasets[i + 1:]))
 
 
 def run_step(step, state: G.TrainState, batch: G.GSTBatch, key):
@@ -171,6 +201,12 @@ def run_experiment(
 
     def routed(tup, step=None):
         return _to_batch(*tup)._replace(graph_ids=route(tup, step=step))
+
+    if table_device_rows:   # a table capped on the device: data off it too
+        ds.keep_on_host()
+        ds_test.keep_on_host()
+    else:
+        _place_datasets(step, state, [ds, ds_test], batch_size)
 
     # the store owns a write-back thread when tiered — release it even
     # when training raises (try/finally), keeping repeated runs leak-free
