@@ -33,8 +33,11 @@ def train_flops_per_graph(cfg: Dict, sampled: int, nodes: float,
     ``edges`` valid edges, the Eq.-1 pooling and the head over the graph's
     ``segments`` valid segments.  Backward counts each matmul's weight
     gradient and its input gradient, except the first layer's input (data),
-    and the transpose of each neighbour sum.  The head is the summed
-    per-segment scalar of the ranking track."""
+    and the transpose of each neighbour sum.  The head is the
+    configuration's: the summed per-segment scalar of the ranking track
+    (``segment_sum``), or a two-layer MLP of width ``hidden`` and
+    ``n_out`` outputs on the pooled embedding (``mlp``), whose loss's few
+    operations per class are not counted."""
     d, f = cfg["hidden"], cfg["n_feat"]
     first = 2.0 * nodes * f * d
     mm = (2.0 * nodes * d * d * (cfg["n_pre"] - 1)
@@ -43,6 +46,10 @@ def train_flops_per_graph(cfg: Dict, sampled: int, nodes: float,
     agg = 2.0 * edges * d * cfg["n_mp"]
     pool = nodes * d
     encoder = first * 2 + mm * 3 + agg * 2 + pool * 2
-    head = 2 * (2.0 * segments * d) + 2.0 * sampled * d
-    pooling = 2.0 * segments + 2.0 * sampled
+    if cfg["head"] == "mlp":
+        head = 3 * (2.0 * d * d + 2.0 * d * cfg["n_out"])
+        pooling = 2.0 * segments * d + 2.0 * sampled * d
+    else:
+        head = 2 * (2.0 * segments * d) + 2.0 * sampled * d
+        pooling = 2.0 * segments + 2.0 * sampled
     return sampled * encoder + head + pooling

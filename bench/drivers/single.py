@@ -1,18 +1,11 @@
-"""The training loop the window drives, built from the program's own pieces
+"""The single-chip training loop, built from the program's own pieces
 exactly as its driver builds them.
 
-``single``: the loop of ``graphs/experiment.py::run_experiment`` (what
+The loop of ``graphs/experiment.py::run_experiment`` (what
 ``launch/train.py`` runs): ``_to_batch`` -> ``store.prepare`` (row
 routing) -> the donated, jitted ``core/gst.py::make_train_step`` ->
 ``block_until_ready`` on the loss, one epoch of ``batch_iterator`` after
-another.
-
-The harness owns only the loop, its timing and its host spans.  A driver is
-built once (the jitted step); ``start`` gives it fresh weights, state and
-batch order from a seed, and ``steps`` yields once per step.  The first
-steps after ``start`` are recorded for the reference.  While ``spans`` is a
-list, each step appends ``(start, end, name)`` by ``time.perf_counter`` for
-its batch build (``bench.batch``) and its step (``bench.step``).
+another.  The protocol a driver keeps is in ``harness/spec.py``.
 """
 from __future__ import annotations
 
@@ -25,53 +18,26 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from harness.proof import avals, recorded, require_off, tree_nbytes
 from repro.core import gst as G
 from repro.graphs import batching as Bt
 from repro.graphs import experiment as EX
 from repro.graphs.gnn import GNNConfig, gnn_init, make_encode_fn
 from repro.optim import make_optimizer
 
-PROOF_STEPS = 3
-ADAM_BETA1 = 0.9     # optim/adamw.py's default, which make_optimizer keeps
 
-
-def _require_off(where: str, values: Dict, off: Dict) -> None:
-    """The harness reproduces the driver's plain path; a default that
-    switches on another path needs the harness to follow it."""
-    for k, v in off.items():
-        if values[k] != v:
-            raise SystemExit(f"{where}: default {k}={values[k]!r} takes a "
-                             f"path this harness does not drive (expects "
-                             f"{v!r})")
-
-
-def tree_nbytes(tree) -> int:
-    return sum(np.asarray(x).nbytes for x in jax.tree_util.tree_leaves(tree))
-
-
-def _avals(tree):
-    return jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
-        if isinstance(x, jax.Array) else x, tree)
-
-
-def _host(tree):
-    return jax.tree_util.tree_map(lambda x: np.asarray(x, np.float32),
-                                  jax.device_get(tree))
-
-
-class SingleDriver:
+class Driver:
     """Encoder, optimizer and the jitted step, built once."""
 
     def __init__(self, cfg: Dict, traffic: Dict, ds, devices):
-        self.cfg, self.traffic, self.ds = cfg, traffic, ds
+        self.cfg, self.traffic, self.ds = cfg, traffic, ds.segmented
         self.devices = devices
         self.B, self.S = traffic["batch_size"], traffic["num_sampled"]
         sig = inspect.signature(EX.run_experiment).parameters
-        _require_off("graphs/experiment.py::run_experiment",
-                     {k: p.default for k, p in sig.items()},
-                     {"table_device_rows": None, "sed_age_weighting": 0.0,
-                      "stale_forecast": False})
+        require_off("graphs/experiment.py::run_experiment",
+                    {k: p.default for k, p in sig.items()},
+                    {"table_device_rows": None, "sed_age_weighting": 0.0,
+                     "stale_forecast": False})
         self.gnn = GNNConfig(backbone=cfg["backbone"], n_feat=cfg["n_feat"],
                              hidden=cfg["hidden"], n_pre=cfg["n_pre"],
                              n_mp=cfg["n_mp"], n_post=cfg["n_post"],
@@ -105,32 +71,14 @@ class SingleDriver:
     def lower_temp_bytes(self) -> int:
         """XLA temp bytes of the compiled step at this cell's shapes."""
         batch, key = self.last
-        compiled = self.step.lower(_avals(self.state), _avals(batch),
+        compiled = self.step.lower(avals(self.state), avals(batch),
                                    key).compile()
         return int(compiled.memory_analysis().temp_size_in_bytes)
 
     def steps(self):
-        """Yields after each step; records the first ``PROOF_STEPS``."""
-        pr = self.proof = {"losses": [], "batches": [], "rngs": [],
-                           "params0": _host((self.state.backbone,
-                                             self.state.head))}
-        for i, (host, key, m) in enumerate(self._steps()):
-            if i < PROOF_STEPS:
-                pr["losses"].append(float(m["loss"]))
-                pr["batches"].append(host)
-                pr["rngs"].append(key)
-                if i == 0:   # Adam's first moment after one step
-                    pr["grads1"] = jax.tree_util.tree_map(
-                        lambda mu: mu / (1 - ADAM_BETA1),
-                        _host(self.state.opt_state["mu"]))
-                    pr["params1"] = _host((self.state.backbone,
-                                           self.state.head))
-                if i == PROOF_STEPS - 1:
-                    pr["params"] = _host((self.state.backbone,
-                                          self.state.head))
-                    pr["table"] = tuple(np.asarray(a) for a in
-                                        jax.device_get(self.state.table))
-            yield m
+        """Yields after each step; records the first steps."""
+        return recorded(self, self._steps(), lambda: tuple(
+            np.asarray(a) for a in jax.device_get(self.state.table)))
 
     def _steps(self):
         brng = np.random.default_rng(self.batch_seed)
@@ -160,14 +108,11 @@ class SingleDriver:
                 self.last = (batch, key)
                 t += 1
                 seg, sv, ids, labels = tup
-                yield ({**seg, "seg_valid": sv, "ids": ids, "labels": labels},
-                       key, m)
+                yield (lambda: {**seg, "seg_valid": sv, "ids": ids,
+                                "labels": labels}, key, m)
 
     def block(self):
         jax.block_until_ready(self.state)
 
     def close(self):
         self.state = self.last = None
-
-
-DRIVERS = {"single": SingleDriver}

@@ -5,17 +5,53 @@ given in ``configs``, and a traffic mix, read from
 ``<bench>/workloads/<traffic>.json``.  Its correctness limits are in
 ``<bench>/limits/<cell>.json``, and each per-layer metric is read by
 ``<bench>/metrics/<metric>.py``.  Nothing here names a cell, a
-configuration or a metric: a new one is a new file and a new entry.
+configuration, a metric or a piece of code that runs one: a new one is a
+new file and a new entry.
+
+The code of a cell is found by name the same way, each piece a module of
+its own (``module``):
+
+* the traffic file's ``driver`` names ``<bench>/drivers/<driver>.py``,
+  which exports ``Driver(cfg, traffic, dataset, devices)``: the program's
+  training loop, built once.  ``start(weight_key, batch_seed, rng_seed)``
+  gives it fresh weights and batch order; ``steps()`` yields the step's
+  metrics (with ``"loss"``) once per step, and records the first
+  ``harness.proof.PROOF_STEPS`` in ``proof`` for the reference
+  (``losses``, ``batches``, ``rngs``, ``params0``, ``grads1``,
+  ``params1``, ``params``, ``table``: what ``harness/check.py`` reads);
+  ``block()`` waits for the device; ``lower_temp_bytes()`` is the
+  compiled step's temp memory; ``close()`` frees it.  It has ``devices``,
+  ``h2d_bytes`` (host bytes put on the device per step, 0 where none) and
+  ``spans``: while a list, each step appends ``(start, end, name)`` by
+  ``time.perf_counter`` (``bench.batch``, ``bench.step``).
+* the configuration's ``dataset`` names ``<bench>/datasets/<dataset>.py``,
+  which exports ``build(cfg, cache)``: the cell's data, built from the
+  configuration or read back from the directory ``cache``, as an object
+  with ``n`` (examples), ``j_max`` (segments an example) and ``stats()``
+  (a dict the per-layer readers read); and ``SOURCES``, the files whose
+  text is part of its cache key.
+* the configuration's ``reference`` names
+  ``<bench>/references/<reference>.py``, the plain reference, which
+  imports nothing of the program.  It exports ``make_step(cfg, traffic,
+  dtype, fault=None, shards=1)``, ``run(cfg, traffic, weight_key, n, j_max,
+  batches, rngs, dtype=float32, fault=None, step=None)`` (a record like
+  the driver's ``proof``; ``batches`` and ``rngs`` are the driver's
+  recorded trees, passed through whole), ``FAULTS`` (the faults
+  ``make_step`` can plant, for ``readings.py``) and
+  ``train_flops_per_graph(cfg, traffic, stats)`` (the useful FLOPs of one
+  example's step, for ``mfu.train``).
 """
 from __future__ import annotations
 
 import importlib.util
 import json
+import re
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Dict, List
 
 BENCH = Path(__file__).resolve().parents[1]
+_MODULES: Dict[Path, object] = {}
 
 
 @dataclass
@@ -29,6 +65,18 @@ class Cell:
     per_layer: List[Dict]
     peaks: Dict
     bench: Path
+
+    @property
+    def driver(self):
+        return module(self.bench, "drivers", self.traffic["driver"])
+
+    @property
+    def dataset(self):
+        return module(self.bench, "datasets", self.config["dataset"])
+
+    @property
+    def reference(self):
+        return module(self.bench, "references", self.config["reference"])
 
 
 def _reports(metric: Dict, cell: str) -> bool:
@@ -56,11 +104,20 @@ def load(cell_name: str, bench: Path = BENCH) -> Cell:
                 peaks=read(bench / "peaks.json"), bench=bench)
 
 
+def module(bench: Path, kind: str, name: str):
+    """The module ``<bench>/<kind>/<name>.py``, loaded once per path."""
+    path = (bench / kind / f"{name}.py").resolve()
+    if path not in _MODULES:
+        if not path.is_file():
+            raise SystemExit(f"no {kind} module {name!r} ({path})")
+        ident = re.sub(r"\W", "_", f"bench_{kind}_{name}_{len(_MODULES)}")
+        spec = importlib.util.spec_from_file_location(ident, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _MODULES[path] = mod
+    return _MODULES[path]
+
+
 def reader(bench: Path, metric: str):
     """The ``read(run)`` function of one per-layer metric."""
-    path = bench / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return module(bench, "metrics", metric).read

@@ -8,8 +8,9 @@ comparison reads every number of ``harness/check.py`` against the float32
 reference (kind ``program``: the lower readings).  On the first
 ``--others`` seeds it also reads the control, the reference computed in
 bfloat16 in the program's place (kind ``control``), and the reference with
-each of ``reference.py``'s faults planted (kinds ``half_batch``,
-``altered``, ``frozen`` and ``beta2``): the upper readings.  A step that
+each of its ``FAULTS`` planted (one kind each, such as ``half_batch``):
+the upper readings.  The driver, dataset and reference are the cell's own
+(``harness/spec.py``).  A step that
 returns its state unchanged reads 1 on ``grad_gap`` and ``change_gap`` by
 construction and is not run.  One JSON line per reading.
 """
@@ -40,20 +41,22 @@ def main(argv=None):
     cell = SPEC.load(args.workload)
     jax = R._configure_jax(cell.bench)
     import jax.numpy as jnp
-    from harness import check, dataset, drivers, reference
+    from harness import check
+    from harness.proof import PROOF_STEPS
 
     devices = jax.devices()
     if devices[0].platform != "tpu" or len(devices) < cell.chips:
         raise SystemExit(f"needs {cell.chips} TPU chip(s)")
     devices = devices[:cell.chips]
-    cfg, traffic = cell.config, cell.traffic
-    ds = dataset.for_config(cfg, cell.bench / ".cache" / "data")
-    driver = drivers.DRIVERS[traffic["driver"]](cfg, traffic, ds, devices)
-    faults = ["half_batch", "altered", "frozen", "beta2"]
+    cfg, traffic, reference = cell.config, cell.traffic, cell.reference
+    data = cell.dataset.build(cfg, cell.bench / ".cache" / "data")
+    driver = cell.driver.Driver(cfg, traffic, data, devices)
+    faults = reference.FAULTS
     steps = {"program": reference.make_step(cfg, traffic, jnp.float32),
              "control": reference.make_step(cfg, traffic, jnp.bfloat16)}
     for f in faults:
-        steps[f] = reference.make_step(cfg, traffic, jnp.float32, f)
+        steps[f] = reference.make_step(cfg, traffic, jnp.float32, f,
+                                       shards=cell.chips)
     out = open(args.out, "w")
     for i in range(args.seeds):
         seed = args.first_seed + 7919 * i
@@ -61,11 +64,11 @@ def main(argv=None):
         wkey, bseed, rseed = R.seeds(seed)
         driver.start(wkey, bseed, rseed)
         gen = driver.steps()
-        for _ in range(drivers.PROOF_STEPS):
+        for _ in range(PROOF_STEPS):
             next(gen)
         gen.close()
         proof = driver.proof
-        ref_args = (cfg, traffic, wkey, ds.n, ds.j_max, proof["batches"],
+        ref_args = (cfg, traffic, wkey, data.n, data.j_max, proof["batches"],
                     proof["rngs"])
         ref = reference.run(*ref_args, step=steps["program"])
         rows = [("program", check.numbers(proof, ref))]

@@ -1,4 +1,5 @@
-"""Plain reference of the timed GST-EFD train step, in straightforward jnp.
+"""Plain reference of the timed GST-EFD train step of a GraphGym SAGE
+encoder, in straightforward jnp.
 
 It imports nothing of the program.  It builds its own weights from the same
 seed with the same ``jax.random`` calls as the published GraphGym layer
@@ -7,19 +8,30 @@ Algorithm 1/2 step with Stale Embedding Dropout (Eq. 1), as the program
 states it: sample S segments per graph (Gumbel top-k over the valid ones),
 encode them (pre MLP, SAGE mean aggregation, post MLP, PReLU, mean pool),
 fill the other slots from the table (zero weight where never written),
-weight by eta, pool, apply the head and the loss, clip the gradients to a
-global norm, take an Adam step, and write the fresh embeddings back.
+weight by eta, then either apply a per-segment scalar head and sum
+(``head`` ``segment_sum``, pairwise hinge loss) or pool and apply a
+two-layer MLP head (``head`` ``mlp``, cross-entropy), clip the gradients
+to a global norm, take an Adam step, and write the fresh embeddings back.
+A batch that carries ``batch_pos`` draws its segments and drops row by
+row, each row from a key folded with its position in the whole batch, as
+data-parallel training does; the data-parallel mean over shards of equal
+size is the mean over the whole batch, so the reference computes it on
+one device.
 
 ``dtype`` float32 runs at HIGHEST matmul precision (the reference);
 bfloat16 runs everything in bfloat16 (the control).  Random draws stay in
 float32 in both, so both see the same segments and drops.
 
-``fault`` plants a fault in the reference put in the program's place:
-``half_batch`` (loss over the first half of the batch only), ``altered``
-(the write-back lands on the next segment's slot), ``frozen`` (parameters
-left as they are after the first step, while the optimizer state and the
-table go on) and ``beta2`` (Adam's second moment decays with
-``FAULT_BETA2`` for 0.999).
+``fault`` plants a fault in the reference put in the program's place
+(``FAULTS``): ``half_batch`` (loss over the first half of the batch
+only), ``altered`` (the write-back lands on the next segment's slot),
+``frozen`` (parameters left as they are after the first step, while the
+optimizer state and the table go on), ``beta2`` (Adam's second moment
+decays with ``FAULT_BETA2`` for 0.999) and ``exchange`` (the table split
+in ``shards`` blocks of rows, each batch row served by the shard that
+holds its slice of the batch, with the exchange between shards left out:
+rows another shard owns read as never written, and their write-backs are
+dropped).
 """
 from __future__ import annotations
 
@@ -30,7 +42,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import counts
+
 FAULT_BETA2 = 0.5
+FAULTS = ["half_batch", "altered", "frozen", "beta2", "exchange"]
 
 
 def _dense(key, d_in, d_out):
@@ -40,9 +55,8 @@ def _dense(key, d_in, d_out):
 
 def init_params(key, cfg: Dict):
     """(backbone, head) with the program's tree layout and key schedule."""
-    if cfg["backbone"] != "sage" or cfg["head"] != "segment_sum":
-        raise ValueError("the reference has a SAGE backbone and a summed "
-                         "per-segment head only")
+    if cfg["backbone"] != "sage":
+        raise ValueError("the reference has a SAGE backbone only")
     d, n_pre, n_mp, n_post = (cfg["hidden"], cfg["n_pre"], cfg["n_mp"],
                               cfg["n_post"])
     keys = jax.random.split(key, n_pre + n_mp + n_post + 1)
@@ -60,7 +74,11 @@ def init_params(key, cfg: Dict):
     for i in range(n_post):
         p["post"].append({"w": _dense(keys[n_pre + n_mp + i], d, d),
                           "b": jnp.zeros((d,)), "prelu": a()})
-    k1, _ = jax.random.split(jax.random.fold_in(key, 1))
+    k1, k2 = jax.random.split(jax.random.fold_in(key, 1))
+    if cfg["head"] == "mlp":
+        n_out = cfg["n_out"]
+        return p, {"w1": _dense(k1, d, d), "b1": jnp.zeros((d,)),
+                   "w2": _dense(k2, d, n_out), "b2": jnp.zeros((n_out,))}
     return p, {"w": _dense(k1, d, 1), "b": jnp.zeros((1,))}
 
 
@@ -87,12 +105,19 @@ def _encode(p, x, edges, ev, nv):
     return jnp.sum(h, 0) / jnp.maximum(jnp.sum(nv), 1)
 
 
-def _draws(rng, t, seg_valid, S):
-    """Sampled segment indices (B, S) and SED uniforms (B, J)."""
+def _draws(rng, t, seg_valid, S, batch_pos=None):
+    """Sampled segment indices (B, S) and SED uniforms (B, J); row by row
+    from keys folded with ``batch_pos`` where given."""
     B, J = seg_valid.shape
     r_sample, r_sed = jax.random.split(jax.random.fold_in(rng, t))
-    g = jax.random.gumbel(r_sample, (B, J))
-    u = jax.random.uniform(r_sed, (B, J))
+    if batch_pos is None:
+        g = jax.random.gumbel(r_sample, (B, J))
+        u = jax.random.uniform(r_sed, (B, J))
+    else:
+        rows = lambda r, draw: jax.vmap(
+            lambda p: draw(jax.random.fold_in(r, p), (J,)))(batch_pos)
+        g = rows(r_sample, jax.random.gumbel)
+        u = rows(r_sed, jax.random.uniform)
     _, idx = jax.lax.top_k(jnp.where(seg_valid > 0, g, -jnp.inf), S)
     return idx, u
 
@@ -107,11 +132,22 @@ def _loss(trainable, cfg, batch, idx, eta, base, rows):
         take(batch["node_valid"])).reshape(B, idx.shape[1], -1)
     h_comb = base.at[b, idx].set(h_s)
     sv = batch["seg_valid"]
+    y = batch["labels"]
+    mean = lambda a: a / jnp.maximum(jnp.sum(sv, -1), 1).reshape(
+        (-1,) + (1,) * (a.ndim - 1))
+    if cfg["head"] == "mlp":
+        pooled = jnp.sum(h_comb * eta[..., None], 1)
+        if cfg["agg"] == "mean":
+            pooled = mean(pooled)
+        z = jax.nn.relu(pooled @ head["w1"] + head["b1"])
+        logp = jax.nn.log_softmax((z @ head["w2"] + head["b2"]).astype(
+            jnp.float32), -1)
+        nll = -jnp.take_along_axis(logp, y[:, None], -1)[:, 0]
+        return jnp.sum(nll * rows) / jnp.sum(rows), h_s
     scal = (h_comb @ head["w"] + head["b"])[..., 0]
     preds = jnp.sum(scal * eta, -1)
     if cfg["agg"] == "mean":
-        preds = preds / jnp.maximum(jnp.sum(sv, -1), 1)
-    y = batch["labels"]
+        preds = mean(preds)
     pair = rows[:, None] * rows[None, :]
     gt = (y[:, None] > y[None, :]).astype(preds.dtype) * pair
     dy = preds[:, None] - preds[None, :]
@@ -119,10 +155,11 @@ def _loss(trainable, cfg, batch, idx, eta, base, rows):
     return loss, h_s
 
 
-def make_step(cfg: Dict, traffic: Dict, dtype, fault: Optional[str] = None):
+def make_step(cfg: Dict, traffic: Dict, dtype, fault: Optional[str] = None,
+              shards: int = 1):
     """``step(trainable, opt, table, batch, rng, t) -> (loss, grads,
     trainable, opt, table)``, jitted; ``grads`` are clipped, as Adam gets
-    them."""
+    them.  ``shards`` only places the ``exchange`` fault."""
     S, p_keep, lr = traffic["num_sampled"], cfg["keep_prob"], cfg["lr"]
     b1, eps, max_norm = 0.9, 1e-8, cfg["max_grad_norm"]
     b2 = FAULT_BETA2 if fault == "beta2" else 0.999
@@ -134,9 +171,16 @@ def make_step(cfg: Dict, traffic: Dict, dtype, fault: Optional[str] = None):
                  for k, v in batch.items()}
         sv, ids = batch["seg_valid"], batch["ids"]
         B, J = sv.shape
-        idx, u = _draws(rng, t, sv, S)
+        idx, u = _draws(rng, t, sv, S, batch.get("batch_pos"))
         fresh = jnp.sum(jax.nn.one_hot(idx, J, dtype=dtype), 1) * sv
-        stale_valid = sv * init[ids].astype(dtype)
+        base, written, w_ids = emb[ids], init[ids], ids
+        if fault == "exchange":   # does the row's shard own its table row?
+            per_shard = -(-emb.shape[0] // shards)
+            local = ids // per_shard == jnp.arange(B) // (B // shards)
+            base = jnp.where(local[:, None, None], base, 0)
+            written = written & local[:, None]
+            w_ids = jnp.where(local, ids, emb.shape[0])     # dropped
+        stale_valid = sv * written.astype(dtype)
         J_i = jnp.sum(sv, -1, keepdims=True)
         drop = (u > p_keep).astype(dtype)
         eta = (fresh * (p_keep + (1 - p_keep) * J_i / S)
@@ -145,7 +189,7 @@ def make_step(cfg: Dict, traffic: Dict, dtype, fault: Optional[str] = None):
         if fault == "half_batch":
             rows = (jnp.arange(B) < B // 2).astype(dtype)
         (loss, h_s), grads = jax.value_and_grad(_loss, has_aux=True)(
-            trainable, cfg, batch, idx, eta, emb[ids], rows)
+            trainable, cfg, batch, idx, eta, base, rows)
         leaves = jax.tree_util.tree_leaves(grads)
         gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g)) for g in leaves))
         scale = jnp.minimum(1, max_norm / jnp.maximum(gnorm, 1e-9))
@@ -167,17 +211,26 @@ def make_step(cfg: Dict, traffic: Dict, dtype, fault: Optional[str] = None):
                 lambda a, b: jnp.where(t >= 1, a, b), trainable, new)
         trainable = new
         w_idx = (idx + 1) % J if fault == "altered" else idx
-        rb = jnp.broadcast_to(ids[:, None], idx.shape)
-        table = (emb.at[rb, w_idx].set(jax.lax.stop_gradient(h_s)),
-                 age.at[rb, w_idx].set(t), init.at[rb, w_idx].set(True))
+        rb = jnp.broadcast_to(w_ids[:, None], idx.shape)
+        table = (emb.at[rb, w_idx].set(jax.lax.stop_gradient(h_s),
+                                       mode="drop"),
+                 age.at[rb, w_idx].set(t, mode="drop"),
+                 init.at[rb, w_idx].set(True, mode="drop"))
         return loss, grads, trainable, {"count": n, "mu": mu, "nu": nu}, table
 
     return jax.jit(step)
 
 
+def train_flops_per_graph(cfg: Dict, traffic: Dict, stats: Dict) -> float:
+    """Useful FLOPs of one example's training step (``counts.py``)."""
+    return counts.train_flops_per_graph(
+        cfg, traffic["num_sampled"], stats["nodes"], stats["edges"],
+        stats["segments"])
+
+
 def run(cfg: Dict, traffic: Dict, seed_key: int, n_rows: int, j_max: int,
         batches: List[Dict], rngs: List, dtype=jnp.float32,
-        fault: Optional[str] = None, step=None) -> Dict:
+        fault: Optional[str] = None, step=None, shards: int = 1) -> Dict:
     """Drive the reference through ``batches`` from its seeded initial
     state; returns host copies of what the comparison reads.  ``step``
     reuses a ``make_step`` of the same settings."""
@@ -193,7 +246,7 @@ def run(cfg: Dict, traffic: Dict, seed_key: int, n_rows: int, j_max: int,
         table = (jnp.zeros((n_rows, j_max, cfg["hidden"]), dtype),
                  jnp.zeros((n_rows, j_max), jnp.int32),
                  jnp.zeros((n_rows, j_max), bool))
-        step = step or make_step(cfg, traffic, dtype, fault)
+        step = step or make_step(cfg, traffic, dtype, fault, shards)
         p0 = jax.device_get(trainable)
         losses, grads1 = [], None
         for t, (batch, rng) in enumerate(zip(batches, rngs)):

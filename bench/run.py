@@ -5,7 +5,9 @@
 Set-up builds the cell's dataset (cached under ``bench/.cache/``), the
 program's driver with weights and batch order from ``--seed``, runs the
 driver's first steps (recorded for the reference) and warms every shape,
-then the window drives training steps for ``--seconds``.  Afterwards the
+then the window drives training steps for ``--seconds``.  The driver, the
+dataset and the reference are the cell's own modules, found by name
+(``harness/spec.py``).  Afterwards the
 recorded steps are compared with the plain reference (``correct``).
 ``--trace 1`` adds a second window, profiled on the device only (at most
 ``TRACE_SECONDS``), and reports the per-layer metrics instead of the
@@ -55,33 +57,20 @@ def _configure_jax(bench: Path):
     return jax
 
 
-def _dataset_stats(ds):
-    """Fill of the padded node slots, and the mean valid nodes, edges and
-    segments a sampled segment and a graph have (per graph, then over
-    graphs, as uniform sampling weighs them)."""
-    import numpy as np
-    sv = ds.seg_valid > 0
-    nodes = ds.node_valid.sum(-1)
-    edges = ds.edge_valid.sum(-1)
-    per_graph = sv.sum(-1)
-    mean = lambda a: float(np.mean((a * sv).sum(-1)
-                                   / np.maximum(per_graph, 1)))
-    return {"seg_fill": float(nodes[sv].sum() / (sv.sum() * ds.m_max)),
-            "nodes": mean(nodes), "edges": mean(edges),
-            "segments": float(per_graph.mean())}
-
-
 class Run:
     """What a per-layer reader sees of one traced run: the traced window's
-    trace and steps, and the rate of the untraced window before it."""
+    trace, steps and program counters (``repro.obs`` registry, counted
+    over the window), the rate of the untraced window before it, the
+    dataset's ``stats()`` and the configuration's reference module."""
 
-    def __init__(self, cell, driver, trace, steps, graphs_per_s):
+    def __init__(self, cell, driver, data, trace, steps, counters,
+                 graphs_per_s):
         self.cell, self.cfg, self.traffic = cell, cell.config, cell.traffic
         self.chips, self.trace, self.steps = cell.chips, trace, steps
-        self.graphs_per_s = graphs_per_s
+        self.counters, self.graphs_per_s = counters, graphs_per_s
         self.h2d_bytes = driver.h2d_bytes
-        self.ds = driver.ds
-        self._stats = None
+        self.reference = cell.reference
+        self._data = data
         kind = driver.devices[0].device_kind
         if kind not in cell.peaks:
             raise SystemExit(f"no peaks for device kind {kind!r} "
@@ -90,9 +79,7 @@ class Run:
 
     @property
     def stats(self):
-        if self._stats is None:
-            self._stats = _dataset_stats(self.ds)
-        return self._stats
+        return self._data.stats()
 
 
 def bench_mark(x):
@@ -114,20 +101,26 @@ def _window(gen, driver, seconds: float):
 
 def _traced_window(jax, gen, driver, seconds: float, mark, keep_trace,
                    name):
-    """A window under a device-only profile, between two runs of ``mark``;
-    returns (trace, steps)."""
+    """A window under a device-only profile, between two runs of ``mark``,
+    with a fresh program registry; returns (trace, steps, counters)."""
     import shutil
     import tempfile
     import trace_reduce as TR
+    from repro.obs.metrics import MetricsRegistry, set_registry
     token = jax.numpy.zeros((), jax.numpy.int32)
     tdir = tempfile.mkdtemp()
     jax.profiler.start_trace(tdir, profiler_options=_profile_options(jax))
     driver.spans = []
+    registry = MetricsRegistry()
+    prev = set_registry(registry)
     t_mark = time.perf_counter()
     token = mark(token).block_until_ready()
     steps, window_s, _ = _window(gen, driver, seconds)
     mark(token).block_until_ready()
+    set_registry(prev)
     jax.profiler.stop_trace()
+    counters = {k: v["value"] for k, v in registry.snapshot().items()
+                if v["type"] == "counter"}
     spans = [(s - t_mark, e - t_mark, n) for s, e, n in driver.spans]
     driver.spans = None
     path = next(Path(tdir).rglob("*.xplane.pb"))
@@ -138,7 +131,7 @@ def _traced_window(jax, gen, driver, seconds: float, mark, keep_trace,
             json.dumps(spans))
     tr = TR.load(str(path), spans, host_window_s=window_s)
     shutil.rmtree(tdir, ignore_errors=True)
-    return tr, steps
+    return tr, steps, counters
 
 
 def run_cell(cell, seed: int, seconds: float, trace: bool,
@@ -156,17 +149,18 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     import gc
 
     import jax.numpy as jnp
-    from harness import check, dataset, drivers, reference
+    from harness import check
+    from harness.proof import PROOF_STEPS
     import trace_reduce as TR
 
     cfg, traffic = cell.config, cell.traffic
-    ds = dataset.for_config(cfg, cell.bench / ".cache" / "data")
-    driver = drivers.DRIVERS[traffic["driver"]](cfg, traffic, ds, devices)
+    data = cell.dataset.build(cfg, cell.bench / ".cache" / "data")
+    driver = cell.driver.Driver(cfg, traffic, data, devices)
     wkey, bseed, rseed = seeds(seed)
     driver.start(wkey, bseed, rseed)
     gen = driver.steps()
-    per_epoch = ds.n // traffic["batch_size"]
-    for _ in range(max(drivers.PROOF_STEPS, per_epoch + 1)):
+    per_epoch = data.n // traffic["batch_size"]
+    for _ in range(max(PROOF_STEPS, per_epoch + 1)):
         next(gen)
     driver.block()
     temp_bytes = driver.lower_temp_bytes()
@@ -180,7 +174,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     steps, window_s, m = _window(gen, driver, seconds)
     graphs_per_s = steps * traffic["batch_size"] / window_s
     if trace:
-        tr, traced_steps = _traced_window(
+        tr, traced_steps, counters = _traced_window(
             jax, gen, driver, min(seconds, TRACE_SECONDS), mark, keep_trace,
             cell.name)
     last_loss = float(m["loss"])
@@ -192,8 +186,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     driver.close()
     gc.collect()
 
-    ref = reference.run(cfg, traffic, wkey, ds.n, ds.j_max,
-                        proof["batches"], proof["rngs"])
+    ref = cell.reference.run(cfg, traffic, wkey, data.n, data.j_max,
+                             proof["batches"], proof["rngs"])
     values = check.numbers(proof, ref)
     finite = bool(jnp.isfinite(last_loss).item())
     correct = check.verdict(values, cell.limits) and finite
@@ -206,7 +200,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
               "failed": 0 if finite else 1}
     metrics = {}
     if trace:
-        run = Run(cell, driver, tr, traced_steps, graphs_per_s)
+        run = Run(cell, driver, data, tr, traced_steps, counters,
+                  graphs_per_s)
         for m_ in cell.per_layer:
             value = SPEC.reader(cell.bench, m_["name"])(run)
             if value is not None:

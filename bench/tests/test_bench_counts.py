@@ -18,7 +18,8 @@ def test_sed_pool_counts_by_hand():
 
 
 def test_train_flops_per_graph_by_hand():
-    cfg = {"hidden": 2, "n_feat": 3, "n_pre": 1, "n_mp": 1, "n_post": 1}
+    cfg = {"hidden": 2, "n_feat": 3, "n_pre": 1, "n_mp": 1, "n_post": 1,
+           "head": "segment_sum"}
     # one segment of 4 nodes and 6 edges:
     #   pre  2*4*3*2 = 48, forward + weight gradient      -> 96
     #   mp   4*4*2*2 = 64, post 2*4*2*2 = 32: 96 x 3      -> 288
@@ -29,17 +30,32 @@ def test_train_flops_per_graph_by_hand():
         + 16 + 60 + 16
 
 
+def test_train_flops_per_graph_mlp_head_by_hand():
+    cfg = {"hidden": 2, "n_feat": 3, "n_pre": 1, "n_mp": 1, "n_post": 1,
+           "head": "mlp", "n_out": 5}
+    # the encoder as above: 96 + 288 + 48 + 16 = 448 per sampled segment;
+    # head 3 x (2*2*2 + 2*2*5) = 84 on the pooled embedding;
+    # pooling 2*7*2 over the graph's segments + 2*1*2 back to the sampled
+    assert counts.train_flops_per_graph(cfg, 1, 4, 6, 7) == 448 + 84 + 28 \
+        + 4
+
+
 def test_mfu_reads_the_untraced_rate():
-    """``mfu.train`` multiplies the useful FLOPs per graph by the rate of
-    the untraced window, which the run hands it as ``graphs_per_s``."""
+    """``mfu.train`` multiplies the useful FLOPs per graph, as the
+    configuration's reference counts them, by the rate of the untraced
+    window, which the run hands it as ``graphs_per_s``, over the chips."""
     from types import SimpleNamespace
 
     from conftest import BENCH
     from harness import spec as SPEC
-    cfg = {"hidden": 2, "n_feat": 3, "n_pre": 1, "n_mp": 1, "n_post": 1}
+    cfg = {"hidden": 2, "n_feat": 3, "n_pre": 1, "n_mp": 1, "n_post": 1,
+           "head": "segment_sum"}
     run = SimpleNamespace(
         cfg=cfg, traffic={"num_sampled": 1}, chips=1, graphs_per_s=1e6,
         stats={"nodes": 4, "edges": 6, "segments": 7},
-        peak={"bf16_flops_per_s": 1e12})
+        peak={"bf16_flops_per_s": 1e12},
+        reference=SPEC.module(BENCH, "references", "gnn"))
     # 524 FLOPs per graph (above) x 1e6 graphs/s over 1e12 FLOP/s
     assert SPEC.reader(BENCH, "mfu.train")(run) == 100.0 * 524e6 / 1e12
+    run.chips = 4
+    assert SPEC.reader(BENCH, "mfu.train")(run) == 100.0 * 524e6 / 4e12

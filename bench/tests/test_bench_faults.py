@@ -1,23 +1,26 @@
 """A run with the timed path broken underneath comes out not correct: the
 step returns its state unchanged, the loss leaves half the batch out, the
 table write-back lands on the wrong segment, the parameters stop moving
-after the first step, or Adam's second moment decays at the wrong rate."""
+after the first step, Adam's second moment decays at the wrong rate, or
+(on four forced host devices, in a child process) the exchange between
+the shards of the table is left out."""
 import functools
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from conftest import CELLS, in_four_devices
+
 import run as R
-from harness import drivers
 from harness import spec as SPEC
 from repro.core import embedding_table as tbl
 from repro.core import gst as G
 
-CELL = "tpugraphs-tiny.train.tiny-single"
+CELL = CELLS[1]
 
 
-def _unchanged(monkeypatch):
+def _unchanged(monkeypatch, bench):
     orig = G.make_train_step
 
     def make(*a, **k):
@@ -30,14 +33,14 @@ def _unchanged(monkeypatch):
     monkeypatch.setattr(G, "make_train_step", make)
 
 
-def _half_batch(monkeypatch):
+def _half_batch(monkeypatch, bench):
     for name in ("ce_loss", "pairwise_hinge_loss"):
         orig = getattr(G, name)
         monkeypatch.setattr(G, name, lambda p, y, orig=orig: orig(
             p[:p.shape[0] // 2], y[:y.shape[0] // 2]))
 
 
-def _altered(monkeypatch):
+def _altered(monkeypatch, bench):
     orig = tbl.update_sampled
 
     def update(table, ids, seg_idx, h_new, step, **kw):
@@ -46,7 +49,7 @@ def _altered(monkeypatch):
     monkeypatch.setattr(tbl, "update_sampled", update)
 
 
-def _frozen(monkeypatch):
+def _frozen(monkeypatch, bench):
     """Optimizer state and table go on; the parameters keep their first
     step's values."""
     orig = G.make_train_step
@@ -64,17 +67,51 @@ def _frozen(monkeypatch):
     monkeypatch.setattr(G, "make_train_step", make)
 
 
-def _beta2(monkeypatch):
-    monkeypatch.setattr(drivers, "make_optimizer", functools.partial(
-        drivers.make_optimizer, b2=0.5))
+def _beta2(monkeypatch, bench):
+    driver = SPEC.module(bench, "drivers", "single")
+    monkeypatch.setattr(driver, "make_optimizer", functools.partial(
+        driver.make_optimizer, b2=0.5))
 
 
 @pytest.mark.parametrize(
     "fault", [_unchanged, _half_batch, _altered, _frozen, _beta2],
     ids=["unchanged", "half_batch", "altered", "frozen", "beta2"])
 def test_fault_is_not_correct(tiny_bench, monkeypatch, fault):
-    fault(monkeypatch)
+    fault(monkeypatch, tiny_bench)
     res = R.run_cell(SPEC.load(CELL, tiny_bench), 3000000041, 0.2,
                      trace=False, require_tpu=False)
     assert res["correct"] is False
     assert list(res)[-1] == "checks"
+
+
+def exchange_left_out(bench):
+    """Each shard answers and writes only the table rows it owns: rows
+    another shard owns read as never written, and their write-backs are
+    dropped."""
+    from repro.dist import exchange as EXC
+
+    def local(self, graph_ids):
+        me = jax.lax.axis_index(self.axis_name)
+        return graph_ids // self.rows == me, graph_ids - me * self.rows
+
+    def lookup(self, table, graph_ids):
+        mine, row = local(self, graph_ids)
+        e, i = tbl.lookup(table, jnp.clip(row, 0, self.rows - 1))
+        return (jnp.where(mine[:, None, None], e, 0),
+                jnp.where(mine[:, None], i, False))
+
+    def update_sampled(self, table, graph_ids, seg_idx, h_new, step):
+        mine, row = local(self, graph_ids)
+        return tbl.update_sampled(table, jnp.where(mine, row, self.rows),
+                                  seg_idx, h_new, step, mode="drop")
+
+    EXC.RingExchange.lookup = lookup
+    EXC.RingExchange.update_sampled = update_sampled
+    res = R.run_cell(SPEC.load(CELLS[4], bench), 3000000043, 0.2,
+                     trace=False, require_tpu=False)
+    assert res["correct"] is False, res["checks"]
+    assert res["checks"]["table_mismatch"]["value"] > 0
+
+
+def test_exchange_left_out_is_not_correct():
+    in_four_devices("test_bench_faults", "exchange_left_out")

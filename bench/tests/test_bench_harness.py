@@ -1,5 +1,5 @@
-"""The harness finds cells, configurations and metrics by name, and refuses
-to run without a TPU."""
+"""The harness finds cells, configurations, metrics, drivers, datasets and
+references by name, and refuses to run without a TPU."""
 import json
 import os
 import shutil
@@ -8,6 +8,7 @@ import sys
 
 from conftest import BENCH, ROOT, make_tree
 
+import run as R
 from harness import spec as SPEC
 
 
@@ -37,6 +38,9 @@ def test_new_cell_config_and_metric_found_by_name(tmp_path):
 
     c = SPEC.load(cell, bench)
     assert c.config["name"] == "tpugraphs-tiny2"
+    assert c.driver.__file__ == str(bench / "drivers" / "single.py")
+    assert c.dataset.__file__ == str(bench / "datasets" / "graphs.py")
+    assert c.reference.__file__ == str(bench / "references" / "gnn.py")
     assert c.traffic == {"driver": "single", "batch_size": 2,
                          "num_sampled": 1}
     assert c.limits == {"grad_gap": 0.5}
@@ -46,6 +50,49 @@ def test_new_cell_config_and_metric_found_by_name(tmp_path):
     # the metric names its cells: another cell does not report it
     other = SPEC.load("tpugraphs-tiny.train.tiny-single", bench)
     assert "probe_metric.train" not in [m["name"] for m in other.per_layer]
+
+
+def test_cell_of_new_files_runs(tmp_path):
+    """A cell whose driver, dataset and reference exist only as new files
+    of the tree, under names the harness has never seen, runs correct; the
+    modules the tiny cells use are taken away first."""
+    bench = make_tree(tmp_path)
+    for kind, old, new in (("drivers", "single", "probe_loop"),
+                           ("datasets", "graphs", "probe_data"),
+                           ("references", "gnn", "probe_ref")):
+        src = bench / kind / f"{old}.py"
+        (bench / kind / f"{new}.py").write_text(src.read_text())
+        src.unlink()
+    cfg = json.loads((bench / "configs" / "tpugraphs-tiny.json").read_text())
+    cfg.update(name="probe", dataset="probe_data", reference="probe_ref")
+    (bench / "configs" / "probe.json").write_text(json.dumps(cfg))
+    (bench / "workloads" / "train.probe.json").write_text(json.dumps(
+        {"driver": "probe_loop", "batch_size": 4, "num_sampled": 2}))
+    cell = "probe.train.probe"
+    shutil.copy(bench / "limits" / "tpugraphs-tiny.train.tiny-single.json",
+                bench / "limits" / f"{cell}.json")
+    spec = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "probe", "source": "test",
+                            "file": "bench/configs/probe.json",
+                            "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": cell, "config": "probe",
+                              "traffic": "train.probe", "chips": 1,
+                              "why": "test"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    c = SPEC.load(cell, bench)
+    assert c.driver.__file__ == str(bench / "drivers" / "probe_loop.py")
+    res = R.run_cell(c, 3000000047, 0.2, trace=False, require_tpu=False)
+    assert res["correct"] is True, res["checks"]
+    assert res["attempted"] > 0
+
+
+def test_dist_driver_reads_the_cli_defaults_without_running():
+    """The data-parallel driver takes ``launch/train_dist.py``'s defaults
+    from its parser, which ``main`` builds, without training."""
+    dist = SPEC.module(BENCH, "drivers", "dist")
+    got = dist.cli_defaults()
+    assert {k: got[k] for k in dist.PLAIN} == dist.PLAIN
+    assert got["epochs"] == 5 and got["devices"] is None
 
 
 def _run(cwd, workload):

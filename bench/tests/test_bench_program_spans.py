@@ -13,7 +13,6 @@ from conftest import BENCH
 import program_spans as PS
 import run as R
 import trace_reduce as TR
-from harness import dataset, drivers
 from harness import spec as SPEC
 from repro.graphs import batching as Bt
 from repro.graphs import experiment as EX
@@ -101,9 +100,9 @@ def _three_steps(driver, ds, seed):
 
 def test_tiny_cell_steps_emit_the_program_spans(tiny_bench):
     c = SPEC.load(CELL, tiny_bench)
-    ds = dataset.for_config(c.config, tiny_bench / ".cache" / "data")
-    driver = drivers.SingleDriver(c.config, c.traffic, ds,
-                                  jax.devices()[:1])
+    data = c.dataset.build(c.config, tiny_bench / ".cache" / "data")
+    driver = c.driver.Driver(c.config, c.traffic, data, jax.devices()[:1])
+    ds = data.segmented
     seed = 3000000041
     off = _three_steps(driver, ds, seed)
     tracer = Tracer()

@@ -81,3 +81,27 @@ def test_recorded_chip_trace():
     assert gaps["bench.batch"] == pytest.approx(0.4732, rel=1e-2)
     idle = TR.window_s(tr) - TR.busy_s(tr)
     assert sum(gaps.values()) == pytest.approx(idle, rel=1e-6)
+
+
+def test_exchange_and_feeder_readers():
+    """``exchange_exposed_ms.train`` reads the exposed collective time per
+    step; ``feeder_blocked_ms.train`` the program's feeder counters.  Both
+    find nothing where there is nothing to read."""
+    from types import SimpleNamespace
+
+    from harness import spec as SPEC
+    bench = DATA.parent
+    exposed = SPEC.reader(bench, "exchange_exposed_ms.train")
+    blocked = SPEC.reader(bench, "feeder_blocked_ms.train")
+    # 0.75 s exposed (above) over 5 steps
+    run = SimpleNamespace(trace=_trace(), steps=5, counters={
+        "feeder.batches": 4.0, "feeder.host_blocked_ms": 10.0})
+    assert exposed(run) == pytest.approx(150.0)
+    assert blocked(run) == pytest.approx(2.5)
+    tr = _trace()
+    quiet = TR.Trace({0: tr.ops[0][:2]}, tr.kernels, {0: []}, tr.spans,
+                     tr.window)
+    assert exposed(SimpleNamespace(trace=quiet, steps=5)) is None
+    assert blocked(SimpleNamespace(counters={})) is None
+    assert blocked(SimpleNamespace(counters={
+        "feeder.device_gathers": 3.0})) is None
