@@ -25,7 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness.proof import avals, recorded, require_off, tree_nbytes
+from harness.proof import (avals, host_batch, recorded, require_off,
+                           tree_nbytes)
 from repro import dist as DT
 from repro.core import gst as G
 from repro.core.embedding_table import init_table
@@ -131,9 +132,7 @@ class Driver:
     def _batch(self, ids):
         """The batch of graphs ``ids`` as the reference reads it, gathered
         from the dataset on the host."""
-        ds = self.ds
-        return {**ds.seg_inputs(ids), "seg_valid": ds.seg_valid[ids],
-                "ids": ids, "labels": ds.labels[ids],
+        return {**host_batch(self.ds, ids),
                 "batch_pos": np.arange(len(ids), dtype=np.int32)}
 
     def _put(self, counter):

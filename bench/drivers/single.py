@@ -5,7 +5,9 @@ The loop of ``graphs/experiment.py::run_experiment`` (what
 ``launch/train.py`` runs): ``_to_batch`` -> ``store.prepare`` (row
 routing) -> the donated, jitted ``core/gst.py::make_train_step`` ->
 ``block_until_ready`` on the loss, one epoch of ``batch_iterator`` after
-another.  The protocol a driver keeps is in ``harness/spec.py``.
+another.  The protocol a driver keeps is in ``harness/spec.py``; the
+batches recorded for the reference are gathered from the dataset's host
+arrays by the yielded ids, not taken from the batch the step was fed.
 """
 from __future__ import annotations
 
@@ -18,7 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness.proof import avals, recorded, require_off, tree_nbytes
+from harness.proof import (avals, host_batch, recorded, require_off,
+                           tree_nbytes)
 from repro.core import gst as G
 from repro.graphs import batching as Bt
 from repro.graphs import experiment as EX
@@ -107,9 +110,7 @@ class Driver:
                                    (t1, time.perf_counter(), "bench.step")]
                 self.last = (batch, key)
                 t += 1
-                seg, sv, ids, labels = tup
-                yield (lambda: {**seg, "seg_valid": sv, "ids": ids,
-                                "labels": labels}, key, m)
+                yield (lambda ids=tup[2]: host_batch(self.ds, ids)), key, m
 
     def block(self):
         jax.block_until_ready(self.state)

@@ -37,6 +37,11 @@ from typing import Dict
 import jax
 import numpy as np
 
+# what ``numbers`` reads, in its order; a limits file names some of these
+NUMBERS = ("loss_gap", "loss1_gap", "grad_gap", "grad_elem_gap",
+           "change_gap", "change_med_gap", "change_elem_gap",
+           "step1_elem_gap", "table_gap", "table1_gap", "table_mismatch")
+
 
 def _leaves(tree) -> Dict[str, np.ndarray]:
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)

@@ -44,6 +44,14 @@ def host(tree):
                                   jax.device_get(tree))
 
 
+def host_batch(ds, ids) -> Dict:
+    """The batch of examples ``ids`` as the reference reads it, gathered
+    from the dataset's host arrays: not what the code under test fed its
+    step, so that a wrong row there shows as a gap."""
+    return {**ds.seg_inputs(ids), "seg_valid": ds.seg_valid[ids],
+            "ids": ids, "labels": ds.labels[ids]}
+
+
 def recorded(driver, steps, table):
     """Yield the metrics of ``steps``, an iterator of ``(batch, key,
     metrics)`` that advances ``driver.state`` by one step each, and record

@@ -15,8 +15,10 @@ from harness import check
 from harness import spec as SPEC
 from harness.proof import PROOF_STEPS
 
-# the limits the control must fail, set from chip readings
-REAL_LIMITS = "tpugraphs-sage.train.encoder-heavy"
+# the limits the control must fail, set from chip readings: those of the
+# real cell on as many chips
+REAL_LIMITS = {1: "tpugraphs-sage.train.encoder-heavy",
+               4: "malnet-sage.train.large-graphs.dp4"}
 
 
 def tiny_run_is_correct(bench, chips):
@@ -66,10 +68,12 @@ def control_fails(bench, chips):
     args = (cfg, traffic, wkey, data.n, data.j_max, d.proof["batches"],
             d.proof["rngs"])
     ref = c.reference.run(*args)
-    assert check.verdict(check.numbers(d.proof, ref), c.limits)
+    values = check.numbers(d.proof, ref)
+    assert tuple(values) == check.NUMBERS
+    assert check.verdict(values, c.limits)
     control = check.numbers(c.reference.run(*args, dtype=jnp.bfloat16), ref)
     limits = json.loads(
-        (BENCH / "limits" / f"{REAL_LIMITS}.json").read_text())
+        (BENCH / "limits" / f"{REAL_LIMITS[chips]}.json").read_text())
     assert not check.verdict(control, limits), control
     for fault in c.reference.FAULTS:
         if fault == "exchange" and chips == 1:

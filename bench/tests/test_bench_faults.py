@@ -1,9 +1,11 @@
 """A run with the timed path broken underneath comes out not correct: the
 step returns its state unchanged, the loss leaves half the batch out, the
 table write-back lands on the wrong segment, the parameters stop moving
-after the first step, Adam's second moment decays at the wrong rate, or
-(on four forced host devices, in a child process) the exchange between
-the shards of the table is left out."""
+after the first step, Adam's second moment decays at the wrong rate, the
+feeder gathers the wrong rows, or (on four forced host devices, in a
+child process) the data-parallel step returns its state unchanged or
+leaves half the batch out, or the exchange between the shards of the
+table is left out."""
 import functools
 
 import jax
@@ -73,9 +75,23 @@ def _beta2(monkeypatch, bench):
         driver.make_optimizer, b2=0.5))
 
 
+def _shifted_rows(monkeypatch, bench):
+    """The feeder's device gather hands the step each example's neighbour
+    in the dataset, while the ids and labels stay right."""
+    from repro.graphs import batching as Bt
+    orig = Bt._take_rows
+
+    def take(arrays, ids, shapes):
+        n = next(iter(arrays.values())).shape[0]
+        return orig(arrays, (ids + 1) % n, shapes)
+    monkeypatch.setattr(Bt, "_take_rows", take)
+
+
 @pytest.mark.parametrize(
-    "fault", [_unchanged, _half_batch, _altered, _frozen, _beta2],
-    ids=["unchanged", "half_batch", "altered", "frozen", "beta2"])
+    "fault", [_unchanged, _half_batch, _altered, _frozen, _beta2,
+              _shifted_rows],
+    ids=["unchanged", "half_batch", "altered", "frozen", "beta2",
+         "shifted_rows"])
 def test_fault_is_not_correct(tiny_bench, monkeypatch, fault):
     fault(monkeypatch, tiny_bench)
     res = R.run_cell(SPEC.load(CELL, tiny_bench), 3000000041, 0.2,
@@ -115,3 +131,19 @@ def exchange_left_out(bench):
 
 def test_exchange_left_out_is_not_correct():
     in_four_devices("test_bench_faults", "exchange_left_out")
+
+
+def dist_fault(bench, name):
+    """The program's step, broken by one of the faults above, under the
+    data-parallel driver of the four-chip cell."""
+    faults = {"unchanged": _unchanged, "half_batch": _half_batch}
+    with pytest.MonkeyPatch.context() as mp:
+        faults[name](mp, bench)
+        res = R.run_cell(SPEC.load(CELLS[4], bench), 3000000047, 0.2,
+                         trace=False, require_tpu=False)
+    assert res["correct"] is False, res["checks"]
+
+
+@pytest.mark.parametrize("fault", ["unchanged", "half_batch"])
+def test_dist_fault_is_not_correct(fault):
+    in_four_devices("test_bench_faults", "dist_fault", fault)

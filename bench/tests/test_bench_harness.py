@@ -6,10 +6,52 @@ import shutil
 import subprocess
 import sys
 
+import pytest
+
 from conftest import BENCH, ROOT, make_tree
 
 import run as R
+from harness import check
 from harness import spec as SPEC
+
+SPEC_FILE = json.loads((ROOT / "BENCHMARK.json").read_text())
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in SPEC_FILE["workloads"]])
+def test_listed_cell_loads_and_limits_name_compared_numbers(cell):
+    """Each cell of the repository's ``BENCHMARK.json`` loads with its
+    configuration, traffic, limits, code modules and metric readers, and
+    its limits name only numbers the comparison reads."""
+    c = SPEC.load(cell)
+    assert c.chips in (1, 4)
+    assert c.driver.Driver and c.dataset.build and c.reference.run
+    for m in c.per_layer:
+        assert callable(SPEC.reader(BENCH, m["name"]))
+    assert c.limits and set(c.limits) <= set(check.NUMBERS)
+
+
+@pytest.mark.parametrize("path", sorted((BENCH / "limits").glob("*.json")),
+                         ids=lambda p: p.stem)
+def test_every_limits_file_names_compared_numbers(path):
+    """Every limits file, also one kept for a cell not yet listed, names
+    only numbers the comparison reads, each with a number."""
+    limits = json.loads(path.read_text())
+    assert limits and set(limits) <= set(check.NUMBERS)
+    assert all(isinstance(v, (int, float)) and not isinstance(v, bool)
+               for v in limits.values())
+
+
+def test_benchmark_file_holds_together():
+    """Metrics' ``workloads`` lists name only listed cells, every
+    configuration is some cell's, and at most half the cells (one at
+    least) ask for four chips."""
+    cells = {w["name"]: w for w in SPEC_FILE["workloads"]}
+    for m in SPEC_FILE["end_to_end"] + SPEC_FILE["per_layer"]:
+        assert set(m.get("workloads", [])) <= set(cells), m["name"]
+    assert ({c["name"] for c in SPEC_FILE["configs"]}
+            == {w["config"] for w in cells.values()})
+    four = sum(w["chips"] == 4 for w in cells.values())
+    assert four <= max(1, len(cells) // 2)
 
 
 def test_new_cell_config_and_metric_found_by_name(tmp_path):
