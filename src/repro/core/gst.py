@@ -103,8 +103,19 @@ def pairwise_hinge_loss(preds, labels):
 # ---------------------------------------------------------------------------
 
 
+def _gathers_itself(seg_inputs) -> bool:
+    """Whether ``seg_inputs`` gathers its own segments (``take`` and
+    ``whole``), as the feeder's handle on a dataset kept on the device
+    does (graphs/batching.py::ResidentSegments)."""
+    return hasattr(seg_inputs, "take") and hasattr(seg_inputs, "whole")
+
+
 def gather_segments(seg_inputs, idx):
-    """Pytree (B, J, ...) gathered at idx (B, S) -> (B, S, ...)."""
+    """Pytree (B, J, ...) gathered at idx (B, S) -> (B, S, ...); an input
+    that gathers its own segments is asked for these alone."""
+    if _gathers_itself(seg_inputs):
+        return seg_inputs.take(idx)
+
     def g(x):
         expand = idx.reshape(idx.shape + (1,) * (x.ndim - 2))
         return jnp.take_along_axis(x, expand.astype(jnp.int32), axis=1)
@@ -115,10 +126,20 @@ def _flatten_bs(tree):
     return jax.tree_util.tree_map(lambda x: x.reshape((-1,) + x.shape[2:]), tree)
 
 
+def _all_segments(seg_inputs):
+    """Every segment of the batch, flattened to (B*J, ...)."""
+    if _gathers_itself(seg_inputs):
+        seg_inputs = seg_inputs.whole()
+    return _flatten_bs(seg_inputs)
+
+
 class GSTBatch(NamedTuple):
     """One batch of segmented inputs.
 
-    seg_inputs: pytree, leaves (B, J_max, ...) — per-segment model inputs.
+    seg_inputs: pytree, leaves (B, J_max, ...) — per-segment model inputs;
+                or an input that gathers its own segments, ``take(idx)``
+                (B, S, ...) and ``whole()`` (B, J_max, ...), as the
+                feeder's handle on a dataset kept on the device does.
     seg_valid:  (B, J_max) 1/0.
     graph_ids:  (B,) int32 row in the historical table.
     labels:     (B,) int32 (ce) or float32 (ranking).
@@ -265,7 +286,7 @@ def make_train_step(
                     state.step - t_age(state.table, batch.graph_ids),
                     0).astype(jnp.float32)
         elif variant.recompute_stale:
-            h_all, _ = encode_fn(state.backbone, _flatten_bs(batch.seg_inputs))
+            h_all, _ = encode_fn(state.backbone, _all_segments(batch.seg_inputs))
             h_stale = jax.lax.stop_gradient(h_all.reshape(B, J, -1))
             stale_valid = batch.seg_valid
         else:  # full / gst_one: no stale path
@@ -300,7 +321,7 @@ def make_train_step(
         def loss_fn(trainable):
             backbone, head = trainable
             if variant.name == "full":
-                h_flat, aux = encode_fn(backbone, _flatten_bs(batch.seg_inputs))
+                h_flat, aux = encode_fn(backbone, _all_segments(batch.seg_inputs))
                 h_comb = h_flat.reshape(B, J, -1)
             else:
                 h_s_flat, aux = encode_fn(backbone, sampled_inputs)
@@ -380,7 +401,7 @@ def make_eval_step(encode_fn: Callable, *, head_mode: str = "mlp",
 
     def step(state: TrainState, batch: GSTBatch):
         B, J = batch.seg_valid.shape
-        h_flat, _ = encode_fn(state.backbone, _flatten_bs(batch.seg_inputs))
+        h_flat, _ = encode_fn(state.backbone, _all_segments(batch.seg_inputs))
         h_all = h_flat.reshape(B, J, -1)
         eta = batch.seg_valid.astype(jnp.float32)
         if head_mode == "segment_sum":
@@ -418,7 +439,7 @@ def make_refresh_step(encode_fn: Callable,
 
     def step(state: TrainState, batch: GSTBatch):
         B, J = batch.seg_valid.shape
-        h_flat, _ = encode_fn(state.backbone, _flatten_bs(batch.seg_inputs))
+        h_flat, _ = encode_fn(state.backbone, _all_segments(batch.seg_inputs))
         h_all = h_flat.reshape(B, J, -1)
         table = t_update_all(state.table, batch.graph_ids, h_all,
                              batch.seg_valid, state.step)

@@ -9,13 +9,18 @@ make this information loss negligible.
 The dataset lives in host numpy.  Its per-segment arrays are also copied
 once to the default device, each example's elements together, when they
 take at most half of the device's free memory less what the step needs
-beside them (``SegmentedDataset.resident``); ``batch_iterator`` then
-gathers each batch there, and otherwise on the host.
+beside them (``SegmentedDataset.resident``).  ``batch_iterator`` then
+hands the step a ``ResidentSegments`` handle: the resident arrays and the
+batch's ids, from which the step gathers only the segments it encodes
+(``take``), or every one (``whole``).  Otherwise it gathers each batch on
+the host.
 """
 from __future__ import annotations
 
+import math
 import threading
 import weakref
+from collections.abc import Mapping
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Dict, Iterator, List, Optional, Tuple
@@ -59,20 +64,114 @@ def _in_rows(a: np.ndarray) -> np.ndarray:
 
 
 @partial(jax.jit, static_argnums=2)
-def _take_rows(arrays: Dict[str, jax.Array], ids,
-               shapes: Tuple[Tuple[str, Tuple[int, ...]], ...]
-               ) -> Dict[str, jax.Array]:
-    """One batch's rows of every resident array, in one dispatch: one
-    dynamic slice per id, stacked, in each field's example shape
-    (``shapes``).  On a TPU v5e (n 64, J 190, m 256, F 140, B 16) this
+def _gather_whole(arrays: Dict[str, jax.Array], ids,
+                  shapes: Tuple[Tuple[str, Tuple[int, ...]], ...]
+                  ) -> Dict[str, jax.Array]:
+    """Every segment of the examples ``ids`` of the resident arrays named
+    in ``shapes``: one dynamic slice per id, stacked, in each field's
+    example shape.  On a TPU v5e (n 64, J 190, m 256, F 140, B 16) this
     took 5.9 ms for ``x`` in rows of 128 and 7.9 ms from (n, J, m, F),
     where ``jnp.take`` first copied the whole array (14.2 ms) and a loop
-    of dynamic updates took 22.9 ms."""
+    of dynamic updates took 22.9 ms; it was the feeder's gather of every
+    batch until the step gathered only its sampled segments."""
     b = ids.shape[0]
     return {k: jnp.stack([lax.dynamic_index_in_dim(arrays[k], ids[i],
                                                    keepdims=False)
                           for i in range(b)]).reshape((b,) + shape)
             for k, shape in shapes}
+
+
+def _segment(a: jax.Array, i, j, shape: Tuple[int, ...]) -> jax.Array:
+    """Segment ``j`` of example ``i`` of the resident array ``a``, whose
+    examples have ``shape`` (J, ...): one dynamic slice of the example's
+    rows of 128 (``_in_rows``), or of the example as it is."""
+    seg = tuple(shape[1:])
+    size = math.prod(seg)
+    if a.shape[1:] == tuple(shape):
+        return lax.dynamic_slice(a, (i, j) + (0,) * len(seg),
+                                 (1, 1) + seg).reshape(seg)
+    if size % 128 == 0:                 # the segment's own whole rows
+        r = size // 128
+        return lax.dynamic_slice(a, (i, j * r, 0), (1, r, 128)).reshape(seg)
+    # the rows that hold the segment, moved back at the example's end so
+    # that the slice is never clamped, then the segment out of them
+    rows = a.shape[1]
+    w = min(rows, -(-size // 128) + 1)
+    start = jnp.minimum(j * size // 128, rows - w)
+    piece = lax.dynamic_slice(a, (i, start, 0), (1, w, 128)).reshape(-1)
+    return lax.dynamic_slice(piece, (j * size - 128 * start,),
+                             (size,)).reshape(seg)
+
+
+@partial(jax.tree_util.register_dataclass, data_fields=("arrays", "ids"),
+         meta_fields=("shapes",))
+@dataclass(frozen=True, eq=False)
+class ResidentSegments(Mapping):
+    """A batch's ``seg_inputs`` left on the device: the resident arrays
+    as ``SegmentedDataset.resident`` placed them, the batch's dataset ids
+    ((B,) int32) and each field's example shape (static).  A pytree whose
+    leaves are the arrays and the ids, so that a jitted step receives the
+    resident copy and gathers from it: ``take(idx)`` the B*S segments it
+    encodes, ``whole()`` every segment.  Read as a mapping (``items()``,
+    ``[k]``) it answers with ``whole()``, gathered where it is called.
+    Its leaves may be abstract (shapes, tracers)."""
+    arrays: Dict[str, jax.Array]
+    ids: jax.Array
+    shapes: Tuple[Tuple[str, Tuple[int, ...]], ...]
+
+    def take(self, idx) -> Dict[str, jax.Array]:
+        """The segments ``idx`` (B, S) of each example, (B, S, ...) a
+        field: one dynamic slice a segment.  An index reads as
+        ``jnp.take_along_axis`` reads it on the gathered batch: a negative
+        one counts from the end, and one out of range gives its fill."""
+        b, s = idx.shape
+        J = self.shapes[0][1][0]
+        idx = jnp.where(idx < 0, idx + J, idx)
+        inside = (idx >= 0) & (idx <= J - 1)
+        out = {}
+        for k, shape in self.shapes:
+            segs = jnp.stack([_segment(self.arrays[k], self.ids[i],
+                                       idx[i, j], shape)
+                              for i in range(b) for j in range(s)]
+                             ).reshape((b, s) + shape[1:])
+            fill = (jnp.nan if jnp.issubdtype(segs.dtype, jnp.inexact)
+                    else jnp.iinfo(segs.dtype).min)
+            out[k] = jnp.where(
+                inside.reshape(inside.shape + (1,) * (len(shape) - 1)),
+                segs, fill)
+        return out
+
+    def whole(self) -> Dict[str, jax.Array]:
+        """Every segment, (B, J, ...) a field: ``seg_inputs(ids)``."""
+        return _gather_whole(self.arrays, self.ids, self.shapes)
+
+    def __getitem__(self, k: str) -> jax.Array:
+        shape = dict(self.shapes)[k]
+        return _gather_whole(self.arrays, self.ids, ((k, shape),))[k]
+
+    def __iter__(self):
+        return (k for k, _ in self.shapes)
+
+    def __len__(self) -> int:
+        return len(self.shapes)
+
+    def __contains__(self, k) -> bool:
+        return k in dict(self.shapes)
+
+    def items(self):
+        return self.whole().items()
+
+    def values(self):
+        return self.whole().values()
+
+
+def _take_rows(arrays: Dict[str, jax.Array], ids,
+               shapes: Tuple[Tuple[str, Tuple[int, ...]], ...]
+               ) -> ResidentSegments:
+    """Where dataset ids meet the resident rows: the handle through which
+    a step gathers the examples ``ids`` of ``arrays`` (each field of
+    example shape ``shapes``), with the ids put on the device."""
+    return ResidentSegments(arrays, jnp.asarray(ids, jnp.int32), shapes)
 
 
 @dataclass
@@ -139,10 +238,12 @@ class SegmentedDataset:
         if self._device is None:
             self._device = {}
 
-    def device_seg_inputs(self, ids: np.ndarray) -> Dict[str, jax.Array]:
-        """``seg_inputs(ids)`` gathered from the resident copy on the
-        device: the same values, bit for bit.  The device gather would
-        clamp an id out of range, so such an id raises here."""
+    def device_seg_inputs(self, ids: np.ndarray) -> ResidentSegments:
+        """The examples ``ids`` of the resident copy, as a handle that the
+        step gathers from: its ``whole()`` is ``seg_inputs(ids)`` and its
+        ``take(idx)`` the segments ``idx`` of it, bit for bit, on the
+        device.  The device gather would clamp an id out of range, so such
+        an id raises here."""
         ids = np.asarray(ids)
         if ids.size and not 0 <= ids.min() <= ids.max() < self.n:
             raise IndexError(f"graph ids outside [0, {self.n})")
@@ -223,11 +324,12 @@ def batch_id_schedule(n: int, batch_size: int, *, rng: np.random.Generator,
 def batch_iterator(ds: SegmentedDataset, batch_size: int, *, rng: np.random.Generator,
                    shuffle: bool = True) -> Iterator[Tuple[Dict, np.ndarray, np.ndarray, np.ndarray]]:
     """Yields (seg_inputs, seg_valid, graph_ids, labels) batches (drop-last).
-    ``seg_inputs`` is gathered on the device from the dataset's resident
-    copy where it has one (decided at the first batch, when the dataset
-    has not decided yet), else on the host; the rest is host numpy.  Each
-    batch's gather is one ``feeder.assemble`` span, closed before the yield
-    so that it never covers the consumer's work, and counts as a
+    ``seg_inputs`` is a ``ResidentSegments`` handle on the dataset's
+    resident copy where it has one (decided at the first batch, when the
+    dataset has not decided yet), from which the step gathers; else the
+    host gather, a dict of numpy arrays.  The rest is host numpy.  Each
+    batch's assembly is one ``feeder.assemble`` span, closed before the
+    yield so that it never covers the consumer's work, and counts as a
     ``feeder.device_gathers`` or ``feeder.host_gathers``."""
     # beside the copy, the device holds one gathered batch
     device = ds.resident(batch_size * ds.seg_nbytes // ds.n) is not None

@@ -39,12 +39,19 @@ class ExperimentResult:
 
 
 def _to_batch(seg_inputs, seg_valid, ids, labels) -> G.GSTBatch:
-    """The batch put on the device, as one ``feeder.put`` span; arrays
-    already there (a resident gather) are not copied."""
+    """The batch put on the device, as one ``feeder.put`` span.  A
+    resident handle (``batching.ResidentSegments``) passes as it is, for
+    the step to gather its segments from, and counts as a
+    ``feeder.step_gathers``."""
     with span("feeder.put"):
-        return G.GSTBatch(
-            {k: jnp.asarray(v) for k, v in seg_inputs.items()},
-            jnp.asarray(seg_valid), jnp.asarray(ids), jnp.asarray(labels))
+        if isinstance(seg_inputs, Bt.ResidentSegments):
+            reg = get_registry()
+            if reg.enabled:
+                reg.inc("feeder.step_gathers")
+        else:
+            seg_inputs = {k: jnp.asarray(v) for k, v in seg_inputs.items()}
+        return G.GSTBatch(seg_inputs, jnp.asarray(seg_valid),
+                          jnp.asarray(ids), jnp.asarray(labels))
 
 
 def _step_temp_bytes(step, state: G.TrainState, batch: G.GSTBatch,

@@ -10,6 +10,9 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import math
+import re
+
 import numpy as np
 import pytest
 
@@ -179,3 +182,63 @@ def test_gst_efd_train_step_compiles(one_chip, compiled_kernels):
     n_pallas = ops.count_pallas_calls(step, state, batch, key)
     assert n_pallas == 5
     assert _mosaic_kernels(step, state, batch, key) == n_pallas
+
+
+def _entry_users(hlo: str, op_name: str):
+    """The output element counts of every instruction of the entry
+    computation that reads the parameter named ``op_name``, and that
+    parameter's element count."""
+    entry = hlo[hlo.index("\nENTRY"):]
+    quoted = op_name.replace("'", "\\'")      # as the HLO text quotes it
+    param = re.search(r"\n\s*(%\S+) = \w+\[([\d,]*)\][^\n]*parameter\(\d+\)"
+                      r"[^\n]*op_name=\"" + re.escape(quoted) + "\"", entry)
+    name, dims = param.group(1), param.group(2)
+    size = lambda d: math.prod(int(x) for x in d.split(",") if x)
+    users = []
+    for line in entry.splitlines():
+        rhs = line.partition(" = ")[2]
+        if re.search(re.escape(name) + r"[,)]", rhs) and "parameter(" not in rhs:
+            out = rhs[:rhs.index("(%")]      # the shapes before the operands
+            users.append(max(size(d) for d in re.findall(r"\[([\d,]*)\]", out)))
+    return users, size(dims)
+
+
+def test_gst_efd_step_reads_resident_rows_only(one_chip, compiled_kernels):
+    """The gst_efd step fed the feeder's handle, compiled for a v5e at the
+    TpuGraphs widths (m 256, F 140, hidden 128): every instruction that
+    reads the resident ``x`` makes at most one example's worth of
+    elements (a relayout, reshape or convert of the whole array would
+    make all of them), and the step needs less scratch than one gathered
+    batch of ``x``."""
+    n, J, m, F, E, B = 6, 24, 256, 140, 64, 4
+    z = lambda *s: np.zeros(s, np.float32)
+    ds = Bt.SegmentedDataset(z(n, J, m, F), np.zeros((n, J, E, 2), np.int32),
+                             z(n, J, E), z(n, J, m), z(n, J),
+                             z(n), J, m, E)
+    handle = ds.device_seg_inputs(np.arange(B))
+    cfg = GNNConfig(backbone="sage", n_feat=F, hidden=HIDDEN, use_pallas=True)
+    opt = make_optimizer("adam", lr=5e-3, max_grad_norm=1.0)
+
+    def init():
+        key = jax.random.key(0)
+        bb = gnn_init(key, cfg)
+        head = G.head_init(jax.random.fold_in(key, 1), HIDDEN, 1,
+                           "segment_sum")
+        return G.TrainState(bb, head, opt.init((bb, head)),
+                            init_table(n, J, HIDDEN), jnp.zeros((), jnp.int32))
+
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+    state = on_chip(jax.eval_shape(init))
+    batch = on_chip(G.GSTBatch(handle, z(B, J), np.zeros(B, np.int32), z(B)))
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    step = G.make_train_step(make_encode_fn(cfg), opt, G.VARIANTS["gst_efd"],
+                             num_sampled=2, head_mode="segment_sum",
+                             loss_kind="pairwise_hinge", agg="sum",
+                             use_pallas=True)
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        state, batch, key).compile()
+    users, size = _entry_users(compiled.as_text(),
+                               "batch.seg_inputs.arrays['x']")
+    assert users and max(users) <= size // n
+    assert compiled.memory_analysis().temp_size_in_bytes < B * J * m * F * 4
