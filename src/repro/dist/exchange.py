@@ -39,7 +39,8 @@ Every strategy ships an ANALYTIC per-device bytes-per-exchange model
 which counts the actual collective traffic in a jaxpr — the parity of the
 two is asserted per strategy in tests/test_exchange_props.py, and
 ``select_exchange`` ("--exchange=auto") picks the min-bytes strategy at
-the current shard count (benchmarked into BENCH_gst_dist.json).
+the current shard count.  The choice is by bytes alone: no chip has yet
+timed the strategies against each other.
 
 Bit-exactness contract (tests/test_exchange_props.py): every strategy is
 pure row selection / single-owner scatter — no cross-shard reductions —
@@ -1107,48 +1108,3 @@ def measured_exchange_bytes(fn, num_shards: int, *args, **kwargs) -> int:
                 total += nbytes * (num_shards - 1)
     return total
 
-
-# ---------------------------------------------------------------------------
-# ring byte accounting (module-level: the PR 3 names, re-exported by
-# dist/table.py for backward compatibility)
-# ---------------------------------------------------------------------------
-
-
-def lookup_exchange_bytes(num_shards: int, b_local: int, j_max: int,
-                          d_h: int, itemsize: int = 4) -> int:
-    """Per-device bytes moved through the ring for ONE lookup: D hops of the
-    (ids int32, emb f32, initialized bool) buffer.  0 when unsharded."""
-    if num_shards <= 1:
-        return 0
-    per_hop = b_local * (4 + j_max * d_h * itemsize + j_max * 1)
-    return num_shards * per_hop
-
-
-def update_sampled_exchange_bytes(num_shards: int, b_local: int, s: int,
-                                  d_h: int, itemsize: int = 4) -> int:
-    """Per-device ring bytes for ONE sampled write-back: (ids, seg_idx,
-    h_new) buffers, D-1 hops (writes need no homecoming hop)."""
-    if num_shards <= 1:
-        return 0
-    per_hop = b_local * (4 + s * 4 + s * d_h * itemsize)
-    return (num_shards - 1) * per_hop
-
-
-def update_all_exchange_bytes(num_shards: int, b_local: int, j_max: int,
-                              d_h: int, itemsize: int = 4) -> int:
-    """Per-device ring bytes for ONE full refresh write: (ids, h_all,
-    seg_valid) buffers, D-1 hops (writes need no homecoming hop)."""
-    if num_shards <= 1:
-        return 0
-    per_hop = b_local * (4 + j_max * d_h * itemsize + j_max * 4)
-    return (num_shards - 1) * per_hop
-
-
-def train_step_exchange_bytes(num_shards: int, b_local: int, j_max: int,
-                              s: int, d_h: int, *, use_table: bool) -> int:
-    """Total per-device ring traffic of one dist train step (lookup +
-    sampled write-back when the variant uses the table)."""
-    if not use_table:
-        return 0
-    return (lookup_exchange_bytes(num_shards, b_local, j_max, d_h)
-            + update_sampled_exchange_bytes(num_shards, b_local, s, d_h))

@@ -11,9 +11,9 @@ classic double buffering).
 
 Both feeders expose the same iterator protocol (the async one is
 single-shot — build one per epoch; the id schedule is the reusable part)
-and count their host-blocked milliseconds — the time ``next()`` spends before a device
-batch is available — so bench_dist.py can show the async pipeline beats
-the synchronous feeder on the same trace (BENCH_gst_dist.json).
+and count their host-blocked milliseconds — the time ``next()`` spends
+before a device batch is available — on the same trace, so that the two
+can be compared where they run.
 
 ``put_fn`` owns what a delivered item IS: launch/train_dist.py's put
 calls ``store.begin`` (tiered-table residency bookkeeping + staging,
@@ -104,7 +104,7 @@ class FeederStats:
 
     def record_batch(self, blocked_ms: float) -> None:
         """One delivered batch: local stats + the registry mirror (the
-        local lists/floats stay for bench_dist and tests)."""
+        local lists/floats stay for the CLI's report and tests)."""
         self.batches += 1
         self.host_blocked_ms += blocked_ms
         self.blocked_per_batch.append(blocked_ms)

@@ -8,10 +8,9 @@ padded to D·R; padding rows are never referenced — graph_ids < n).
 FreshGNN / Bai et al. (PAPERS.md) motivate the design: the historical
 embedding store is the scaling bottleneck, so it must be partitioned with
 the compute instead of replicated.  HOW the shards exchange lookups and
-write-backs is a pluggable strategy since ISSUE 5 — dist/exchange.py owns
+write-backs is a pluggable strategy — dist/exchange.py owns
 the ring / alltoall / bucketed implementations and their bytes models;
-this module keeps the row geometry (pad/unpad, rows_per_shard) and
-re-exports the PR 3 ring API for its existing callers.
+this module keeps the row geometry (pad/unpad, rows_per_shard).
 """
 from __future__ import annotations
 
@@ -21,21 +20,6 @@ from repro.kernels.ops import pad_leading
 # canonical row-partition definitions live with the embedding store
 # (store/base.py), which owns row geometry now
 from repro.store.base import padded_rows, rows_per_shard  # noqa: F401
-
-# byte accounting + the ring strategy moved to dist/exchange.py (ISSUE 5);
-# re-exported here so PR 3-era callers keep working unchanged.  (The
-# module-level *_exchange_bytes models are the PR 3 f32 ring; the
-# strategy methods carry the compressed --payload-dtype models.)
-from repro.dist.exchange import (  # noqa: F401
-    PAYLOAD_DTYPES, PayloadCodec, RingExchange, lookup_exchange_bytes,
-    train_step_exchange_bytes, update_all_exchange_bytes,
-    update_sampled_exchange_bytes)
-
-# prefetch-lane host planning (ISSUE 9) lives beside the row geometry it
-# depends on: consumer_shards maps write rows to the shard whose slice of
-# the next batch reads them (the contiguous split defined above)
-from repro.dist.exchange import (  # noqa: F401
-    consumer_shards, plan_patch_capacity, required_patch_capacity)
 
 
 def pad_table(table: tbl.EmbeddingTable, num_shards: int) -> tbl.EmbeddingTable:
@@ -48,32 +32,3 @@ def unpad_table(table: tbl.EmbeddingTable, n_rows: int) -> tbl.EmbeddingTable:
     return tbl.EmbeddingTable(table.emb[:n_rows], table.age[:n_rows],
                               table.initialized[:n_rows])
 
-
-# ---------------------------------------------------------------------------
-# PR 3 ring entry points (now thin wrappers over the ring strategy)
-# ---------------------------------------------------------------------------
-
-
-def _ring(axis_name: str, num_shards: int, rows: int) -> RingExchange:
-    return RingExchange(axis_name=axis_name, num_shards=num_shards,
-                        rows=rows)
-
-
-def ring_lookup(table, graph_ids, *, axis_name: str, num_shards: int,
-                rows: int):
-    """Distributed ``tbl.lookup`` over the ring (see RingExchange)."""
-    return _ring(axis_name, num_shards, rows).lookup(table, graph_ids)
-
-
-def ring_update_sampled(table, graph_ids, seg_idx, h_new, step, *,
-                        axis_name: str, num_shards: int, rows: int):
-    """Distributed ``tbl.update_sampled`` over the ring (see RingExchange)."""
-    return _ring(axis_name, num_shards, rows).update_sampled(
-        table, graph_ids, seg_idx, h_new, step)
-
-
-def ring_update_all(table, graph_ids, h_all, seg_valid, step, *,
-                    axis_name: str, num_shards: int, rows: int):
-    """Distributed ``tbl.update_all`` over the ring (see RingExchange)."""
-    return _ring(axis_name, num_shards, rows).update_all(
-        table, graph_ids, h_all, seg_valid, step)

@@ -121,7 +121,7 @@ def count_pallas_calls(fn, *args, **kwargs) -> int:
 
     The fused-path contract (one batched kernel launch per message-passing
     layer rather than one per vmapped segment) is asserted with this in
-    tests/test_fused_path.py and recorded by benchmarks/bench_step.py.
+    tests/test_fused_path.py.
 
     The recursion (iter_jaxpr_eqns) sees through pjit, scan/while bodies,
     custom-VJP wrappers AND ``shard_map`` — the dist/ subsystem uses that

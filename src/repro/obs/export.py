@@ -21,10 +21,9 @@ JSONL stream format (one JSON object per line):
     {"type": "summary", "wall_s": ..., "metrics": {name: value|summary},
      **extra}                          # cumulative, report-grade
 
-The final summary dict is also RETURNED so the tracked-benchmark writers
-(benchmarks/bench_*.py) merge it into their BENCH_*.json entries, and the
-CI obs gate (``python -m repro.obs.gate``) asserts SLOs against the same
-stream.
+The final summary dict is also RETURNED to the caller, and the CI obs
+gate (``python -m repro.obs.gate``) asserts its count contracts against
+the same stream.
 """
 from __future__ import annotations
 
@@ -223,8 +222,8 @@ class Obs:
             self.exporter.event(event, **payload)
 
     def summary(self, **extra) -> Dict:
-        """Cumulative report-grade dict (registry summary + extras) —
-        what the BENCH_*.json writers merge; does NOT close anything."""
+        """Cumulative report-grade dict (registry summary + extras); does
+        NOT close anything."""
         return {"metrics": _jsonable(self.registry.summary()),
                 **_jsonable(extra)}
 

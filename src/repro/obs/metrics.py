@@ -319,7 +319,7 @@ class MetricsRegistry:
 
     def summary(self) -> Dict[str, object]:
         """Flat report-grade dict: counters/gauges -> value, histograms ->
-        summarize() dict.  This is what the BENCH_*.json writers merge."""
+        summarize() dict: the JSONL stream's ``summary`` record."""
         out: Dict[str, object] = {}
         with self._lock:
             metrics = dict(self._metrics)

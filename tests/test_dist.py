@@ -26,7 +26,7 @@ from repro.core import embedding_table as tbl
 from repro.core import gst as G
 from repro.core.embedding_table import init_table
 from repro.dist import pipeline as DP
-from repro.dist import table as dtbl
+from repro.dist.exchange import RingExchange
 from repro.graphs import data as D
 from repro.graphs.gnn import GNNConfig, gnn_init, make_encode_fn
 from repro.optim import make_optimizer
@@ -87,8 +87,6 @@ def _random_table(n, J, d, seed=0):
 
 @pytest.mark.parametrize("n_shards", SHARD_COUNTS)
 def test_ring_lookup_bit_exact(n_shards):
-    from functools import partial
-
     from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
@@ -97,10 +95,10 @@ def test_ring_lookup_bit_exact(n_shards):
     ids = jnp.asarray(np.random.default_rng(1).permutation(n)[:B], jnp.int32)
     ctx = DT.make_context(DT.make_dist_mesh(n_shards), n)
     dev = DT.device_table(ctx, table)
+    ring = RingExchange(axis_name=DT.AXIS, num_shards=ctx.num_shards,
+                        rows=ctx.rows_per_shard)
     f = shard_map(
-        partial(dtbl.ring_lookup, axis_name=DT.AXIS,
-                num_shards=ctx.num_shards, rows=ctx.rows_per_shard),
-        mesh=ctx.mesh,
+        ring.lookup, mesh=ctx.mesh,
         in_specs=(tbl.EmbeddingTable(P(DT.AXIS), P(DT.AXIS), P(DT.AXIS)),
                   P(DT.AXIS)),
         out_specs=(P(DT.AXIS), P(DT.AXIS)), check_rep=False)
@@ -113,8 +111,6 @@ def test_ring_lookup_bit_exact(n_shards):
 
 @pytest.mark.parametrize("n_shards", SHARD_COUNTS)
 def test_ring_update_sampled_bit_exact(n_shards):
-    from functools import partial
-
     from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
@@ -127,10 +123,10 @@ def test_ring_update_sampled_bit_exact(n_shards):
     step = jnp.asarray(7, jnp.int32)
     ctx = DT.make_context(DT.make_dist_mesh(n_shards), n)
     tspec = tbl.EmbeddingTable(P(DT.AXIS), P(DT.AXIS), P(DT.AXIS))
+    ring = RingExchange(axis_name=DT.AXIS, num_shards=ctx.num_shards,
+                        rows=ctx.rows_per_shard)
     f = shard_map(
-        partial(dtbl.ring_update_sampled, axis_name=DT.AXIS,
-                num_shards=ctx.num_shards, rows=ctx.rows_per_shard),
-        mesh=ctx.mesh,
+        ring.update_sampled, mesh=ctx.mesh,
         in_specs=(tspec, P(DT.AXIS), P(DT.AXIS), P(DT.AXIS), P()),
         out_specs=tspec, check_rep=False)
     bsh = DT.batch_sharding(ctx)
@@ -144,18 +140,18 @@ def test_ring_update_sampled_bit_exact(n_shards):
 
 
 def test_exchange_bytes_accounting():
-    assert dtbl.lookup_exchange_bytes(1, 8, 4, 16) == 0
-    assert dtbl.update_sampled_exchange_bytes(1, 8, 1, 16) == 0
+    def ring(num_shards):   # the f32 payload
+        return RingExchange(axis_name=DT.AXIS, num_shards=num_shards, rows=1)
+
+    assert ring(1).lookup_bytes(8, 4, 16) == 0
+    assert ring(1).update_sampled_bytes(8, 1, 16) == 0
     # lookup: D hops of the (ids, emb, init) buffer (answers must come home)
-    assert dtbl.lookup_exchange_bytes(4, 2, 3, 8) == \
-        4 * 2 * (4 + 3 * 8 * 4 + 3)
+    assert ring(4).lookup_bytes(2, 3, 8) == 4 * 2 * (4 + 3 * 8 * 4 + 3)
     # writes: D-1 hops of the (ids, seg_idx, h_new) buffer (no homecoming)
-    assert dtbl.update_sampled_exchange_bytes(4, 2, 1, 8) == \
-        3 * 2 * (4 + 4 + 8 * 4)
-    assert dtbl.train_step_exchange_bytes(4, 2, 3, 1, 8, use_table=False) == 0
-    assert dtbl.train_step_exchange_bytes(4, 2, 3, 1, 8, use_table=True) == \
-        dtbl.lookup_exchange_bytes(4, 2, 3, 8) + \
-        dtbl.update_sampled_exchange_bytes(4, 2, 1, 8)
+    assert ring(4).update_sampled_bytes(2, 1, 8) == 3 * 2 * (4 + 4 + 8 * 4)
+    assert ring(4).train_step_bytes(2, 3, 1, 8, use_table=False) == 0
+    assert ring(4).train_step_bytes(2, 3, 1, 8, use_table=True) == \
+        ring(4).lookup_bytes(2, 3, 8) + ring(4).update_sampled_bytes(2, 1, 8)
 
 
 # ---------------------------------------------------------------------------

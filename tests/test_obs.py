@@ -461,7 +461,8 @@ def test_store_publish_counters_mirrors_and_survives_reset(dataset):
         # publishing again without new work is a no-op (diff-publish)
         store.publish_counters()
         assert reg.snapshot()["store.lookups"]["value"] == c.lookups
-        # the counters-reset idiom (bench_store, cache.flush) re-baselines:
+        # the counters-reset idiom (cache.flush, the serve engine's warmup)
+        # re-baselines:
         # registry values stay cumulative, no double count, no negatives
         before = reg.snapshot()["store.lookups"]["value"]
         store.counters = StoreCounters()

@@ -1,4 +1,4 @@
-"""Memory observability (src/repro/obs/memory.py + gate) — ISSUE 8 contract.
+"""Memory observability (src/repro/obs/memory.py) and GST's constant-memory claim.
 
   * the probe captures compiled memory/cost stats once per (site, shape
     signature) and only counts calls afterwards;
@@ -14,9 +14,9 @@
   * the tiered store's host-tier byte gauge equals snapshot() nbytes;
   * when memory_analysis is unavailable the probe degrades to the
     accounting-only mode instead of raising;
-  * the memory gate passes on flat GST temp and fails when the sweep
-    shows growth (and when the full-graph control stops growing);
-  * bench_diff joins merge-keyed BENCH files and reports numeric drift;
+  * the train step's compiled temp bytes stay flat while the graphs grow
+    (every constant-memory variant, and the step that gathers from the
+    resident copy), and the full-graph control grows;
   * Obs --mem-probe writes the per-site memory event ahead of the final
     summary record and restores the global probe on close.
 """
@@ -30,6 +30,7 @@ import pytest
 
 from repro.core import gst as G
 from repro.dist import pipeline as DP
+from repro.graphs import batching as Bt
 from repro.graphs import data as D
 from repro.graphs.gnn import GNNConfig, gnn_init, make_encode_fn
 from repro.kernels.ops import max_intermediate_bytes
@@ -38,7 +39,6 @@ from repro.obs import (MemoryProbe, MetricsRegistry, NullProbe, Obs,
                        null_tracer, probe_jit, set_probe, set_registry,
                        set_tracer, shape_signature, tree_nbytes,
                        validate_chrome_trace)
-from repro.obs.gate import GateFailure, check_memory_json
 from repro.obs.trace import Tracer
 from repro.optim import make_optimizer
 from repro.roofline.analysis import (compiled_memory_stats,
@@ -70,16 +70,16 @@ def _clean_globals():
     set_probe(null_probe())
 
 
-def _state(ds):
-    cfg = GNNConfig(backbone="sage", n_feat=ds.x.shape[-1], hidden=HID)
+def _state(ds, hidden=HID):
+    cfg = GNNConfig(backbone="sage", n_feat=ds.x.shape[-1], hidden=hidden)
     enc = make_encode_fn(cfg)
     key = jax.random.key(0)
     bb = gnn_init(key, cfg)
-    head = G.head_init(jax.random.fold_in(key, 1), HID, 5, "mlp")
+    head = G.head_init(jax.random.fold_in(key, 1), hidden, 5, "mlp")
     opt = make_optimizer("adam", lr=5e-3)
     from repro.core import embedding_table as tbl
     return enc, opt, G.TrainState(bb, head, opt.init((bb, head)),
-                                  tbl.init_table(ds.n, ds.j_max, HID),
+                                  tbl.init_table(ds.n, ds.j_max, hidden),
                                   jnp.zeros((), jnp.int32))
 
 
@@ -375,88 +375,68 @@ def test_probe_raises_on_tpu_instead_of_degrading(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# the memory gate
+# train-step constant-memory claim, measured
 # ---------------------------------------------------------------------------
 
-
-def _mem_payload(gst=1.05, full=5.0, stream=1.0, bound_ok=True,
-                 ladder=800_000):
-    return {"benchmark": "gst_memory", "unit": "bytes", "runs": {
-        "k=1": {"summary": {
-            "gst_temp_ratio_max_over_min": gst,
-            "full_temp_ratio_max_over_min": full,
-            "streaming_temp_ratio_max_over_min": stream,
-            "streaming_bound_ok": bound_ok,
-            "ladder_total_peak_bytes": ladder,
-        }}}}
+# SAGE at hidden 32, segments of at most 32 nodes, one batch of 4 graphs;
+# the graphs grow from 2 to 8 communities, so J grows at a fixed segment
+# shape
+SWEEP_COMMS = ((2, 3), (8, 9))
+SWEEP_HID, SWEEP_SEG_NODES, SWEEP_GRAPHS = 32, 32, 4
 
 
-def _write(tmp_path, payload, name="mem.json"):
-    p = tmp_path / name
-    p.write_text(json.dumps(payload))
-    return str(p)
+@pytest.fixture(scope="module")
+def size_sweep():
+    return [Bt.segment_dataset(
+        D.make_malnet_like(n_graphs=SWEEP_GRAPHS, seed=0, comm_range=comm),
+        SWEEP_SEG_NODES, method="bfs", seed=0) for comm in SWEEP_COMMS]
 
 
-def test_memory_gate_passes_on_flat_gst(tmp_path):
-    path = _write(tmp_path, _mem_payload())
-    lines = check_memory_json(path, mem_epsilon=0.25, stream_epsilon=0.01,
-                              growth_floor=2.0, ladder_budget=1_000_000)
-    assert len(lines) == 1 and "flat" in lines[0]
+def _train_step_temp_bytes(ds, variant: str, resident: bool) -> int:
+    """Temp (activation and workspace) bytes of the compiled train step
+    of ``variant`` on one batch of every graph of ``ds``; ``resident``
+    feeds it a ``ResidentSegments`` handle, from which the step gathers."""
+    enc, opt, state = _state(ds, hidden=SWEEP_HID)
+    ids = np.arange(ds.n)
+    seg_inputs = (ds.device_seg_inputs(ids) if resident else
+                  {k: jnp.asarray(v) for k, v in ds.seg_inputs(ids).items()})
+    batch = G.GSTBatch(seg_inputs, jnp.asarray(ds.seg_valid[ids]),
+                       jnp.asarray(ids, jnp.int32),
+                       jnp.asarray(ds.labels[ids]))
+    step = jax.jit(G.make_train_step(enc, opt, G.VARIANTS[variant],
+                                     keep_prob=0.5),
+                   donate_argnums=(0,))
+    mem = compiled_memory_stats(
+        step.lower(state, batch, jax.random.key(0)).compile())
+    if mem is None:
+        pytest.skip("memory_analysis unavailable on this backend")
+    return mem["temp_size_in_bytes"]
 
 
-@pytest.mark.parametrize("payload,msg", [
-    (_mem_payload(gst=1.5), "constant-memory claim"),
-    (_mem_payload(full=1.2), "vacuous"),
-    (_mem_payload(stream=1.3), "chunk"),
-    (_mem_payload(bound_ok=False), "bound"),
-    (_mem_payload(ladder=2_000_000), "budget"),
+@pytest.mark.parametrize("variant,resident,flat", [
+    ("gst_e", False, True),
+    ("gst_ef", False, True),
+    ("gst_ed", False, True),
+    ("gst_efd", False, True),
+    ("gst_one", False, True),
+    ("gst_efd", True, True),      # the step gathers from the resident copy
+    ("full", False, False),       # the control: every segment backprops
 ])
-def test_memory_gate_fails_on_each_violation(tmp_path, payload, msg):
-    path = _write(tmp_path, payload)
-    with pytest.raises(GateFailure, match=msg):
-        check_memory_json(path, mem_epsilon=0.25, stream_epsilon=0.01,
-                          growth_floor=2.0, ladder_budget=1_000_000)
-
-
-def test_memory_gate_rejects_wrong_file_kind(tmp_path):
-    path = _write(tmp_path, {"benchmark": "gst_step", "runs": {}})
-    with pytest.raises(GateFailure, match="not a gst_memory"):
-        check_memory_json(path, mem_epsilon=0.25, stream_epsilon=0.01,
-                          growth_floor=2.0, ladder_budget=None)
-
-
-# ---------------------------------------------------------------------------
-# bench_diff
-# ---------------------------------------------------------------------------
-
-
-def test_bench_diff_reports_numeric_drift(tmp_path):
-    from repro.obs.bench_diff import diff_files
-    base = {"benchmark": "gst_memory", "runs": {
-        "k=1": {"summary": {"a": 100, "nested": [{"b": 2.0}]},
-                "config": {"hidden": 32}}}}
-    fresh = json.loads(json.dumps(base))
-    fresh["runs"]["k=1"]["summary"]["a"] = 140          # +40%
-    fresh["runs"]["k=1"]["summary"]["new_leaf"] = 1
-    report = diff_files(_write(tmp_path, fresh, "fresh.json"),
-                        _write(tmp_path, base, "base.json"),
-                        tolerance=0.25)
-    (item,) = report["common"]
-    by_metric = {d["metric"]: d for d in item["drift"]}
-    assert by_metric["summary.a"]["rel_delta"] == pytest.approx(0.4)
-    assert by_metric["summary.new_leaf"]["note"] == "missing in baseline"
-    assert "config.hidden" not in by_metric        # config never diffed
-
-
-def test_bench_diff_disjoint_keys_not_fatal(tmp_path):
-    from repro.obs.bench_diff import diff_files
-    a = {"benchmark": "gst_memory", "runs": {"k=1": {"summary": {"a": 1}}}}
-    b = {"benchmark": "gst_memory", "runs": {"k=2": {"summary": {"a": 1}}}}
-    report = diff_files(_write(tmp_path, a, "a.json"),
-                        _write(tmp_path, b, "b.json"), tolerance=0.25)
-    assert report["common"] == []
-    assert report["only_fresh"] == ["k=1"]
-    assert report["only_baseline"] == ["k=2"]
+def test_train_step_temp_flat_across_graph_size(size_sweep, variant,
+                                                resident, flat):
+    """GST's constant-memory claim: with the segment budget fixed, the
+    train step's temp bytes stay within 1.25x while the graphs (and J)
+    grow, and the full-graph control grows at least 2x over the same
+    sizes, so that the sweep has teeth."""
+    small, large = size_sweep
+    assert large.j_max >= 2 * small.j_max, (small.j_max, large.j_max)
+    temps = [_train_step_temp_bytes(ds, variant, resident)
+             for ds in size_sweep]
+    ratio = temps[1] / temps[0]
+    if flat:
+        assert ratio <= 1.25, (variant, small.j_max, large.j_max, temps)
+    else:
+        assert ratio >= 2.0, (variant, small.j_max, large.j_max, temps)
 
 
 # ---------------------------------------------------------------------------

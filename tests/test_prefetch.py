@@ -391,10 +391,6 @@ def test_required_and_plan_patch_capacity():
     a, b = np.asarray([0, 1, 8, 9]), np.asarray([4, 5, 12, 13])
     assert EX.plan_patch_capacity([a, b, a], num_shards=2, rows=8) == 1
     assert EX.plan_patch_capacity([a, a, b], num_shards=2, rows=8) == 2
-    # re-exported through dist.table like the other planners
-    from repro.dist import table as dtbl
-    assert dtbl.plan_patch_capacity is EX.plan_patch_capacity
-    assert dtbl.consumer_shards is EX.consumer_shards
 
 
 # ---------------------------------------------------------------------------

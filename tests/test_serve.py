@@ -280,8 +280,12 @@ def test_engine_cache_disabled_always_encodes():
     g = _graph(0)
     engine.process([g], window=1)
     n1 = engine.stats.encoded_segments
+    launches1 = engine.stats.encode_launches
     engine.process([g], window=1)
     assert engine.stats.encoded_segments == 2 * n1
+    # with no cache, the repeat launches the encoder again
+    assert launches1 > 0
+    assert engine.stats.encode_launches == 2 * launches1
     assert engine.stats.cache == {}
 
 

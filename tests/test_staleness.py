@@ -417,6 +417,7 @@ def test_store_forecast_without_step_hints_is_byte_identical():
         assert _tree_bitwise(snaps[0], snaps[1])
         fstats = stores[1].stats()["forecast"]
         assert fstats["forecast_rows"] == 0  # never activated without hints
+        assert fstats["observed_rows"] > 0   # the evictions still fed it
     finally:
         for s in stores:
             s.close()

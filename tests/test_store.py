@@ -558,17 +558,20 @@ def test_tiered_delta_gate_skips_static_rows():
     table = store.init_device_table()
     v = np.full((1, 1, 4), 2.0, np.float32)
 
-    def write(table, slots, val, t):
-        return tbl.update_sampled(table, jnp.asarray(slots),
-                                  jnp.zeros((1, 1), jnp.int32),
-                                  jnp.asarray(val), t)
+    def residency(store, table, val, t):
+        """Row 0 faults in, is written ``val`` at step ``t``, and is
+        evicted by row 1."""
+        table, slots = store.prepare(table, np.asarray([0]))
+        table = tbl.update_sampled(table, jnp.asarray(slots),
+                                   jnp.zeros((1, 1), jnp.int32),
+                                   jnp.asarray(val), t)
+        table, _ = store.prepare(table, np.asarray([1]))
+        store.flush_writebacks()
+        return table
 
     # first residency: the init flip (False -> True) forces admission even
     # though the gate is on — first writes always reach the host tier
-    table, slots = store.prepare(table, np.asarray([0]))
-    table = write(table, slots, v, 0)
-    table, _ = store.prepare(table, np.asarray([1]))     # evicts row 0
-    store.flush_writebacks()
+    table = residency(store, table, v, 0)
     assert store.counters.wb_skipped_rows == 0
     assert np.array_equal(store._host.emb[0], v[0])
 
@@ -576,10 +579,7 @@ def test_tiered_delta_gate_skips_static_rows():
     # host emb write (stale by < wb_threshold) but still lands the age.
     # (Refetching row 0 evicts the never-written row 1, whose delta is 0
     # and init unchanged — also skipped, hence the count of 2.)
-    table, slots = store.prepare(table, np.asarray([0]))
-    table = write(table, slots, v + 0.1, 7)
-    table, _ = store.prepare(table, np.asarray([1]))     # evicts row 0
-    store.flush_writebacks()
+    table = residency(store, table, v + 0.1, 7)
     assert store.counters.wb_skipped_rows == 2
     assert store.counters.wb_skipped_bytes == 2 * 1 * 4 * 4
     assert np.array_equal(store._host.emb[0], v[0])      # stale, bounded
@@ -587,13 +587,22 @@ def test_tiered_delta_gate_skips_static_rows():
 
     # third residency: movement past the threshold is admitted (the
     # static row 1 eviction in between is skipped again)
-    table, slots = store.prepare(table, np.asarray([0]))
-    table = write(table, slots, v + 3.0, 9)
-    table, _ = store.prepare(table, np.asarray([1]))
-    store.flush_writebacks()
+    table = residency(store, table, v + 3.0, 9)
     assert store.counters.wb_skipped_rows == 3
     assert np.array_equal(store._host.emb[0], v[0] + 3.0)
     assert store.stats()["wb_threshold"] == 0.5
+
+    # the skipped bytes never crossed: the same trace through the ungated
+    # store migrates exactly them more
+    ungated = TieredStore(4, 1, 4, device_rows=1)
+    table = ungated.init_device_table()
+    for val, t in ((v, 0), (v + 0.1, 7), (v + 3.0, 9)):
+        table = residency(ungated, table, val, t)
+    gated_bytes = store.stats()["migration_bytes"]
+    assert gated_bytes == (ungated.stats()["migration_bytes"]
+                           - store.counters.wb_skipped_bytes)
+    assert gated_bytes < ungated.stats()["migration_bytes"]
+    ungated.close()
     store.close()
 
 
@@ -609,6 +618,18 @@ def test_tiered_gate_off_by_default_and_counts_zero():
     assert store.counters.wb_skipped_rows == 0
     assert store.counters.wb_skipped_bytes == 0
     assert store.stats()["wb_skipped_rows"] == 0
+    store.close()
+
+    # a tier that holds every row migrates nothing once each row is in
+    store = TieredStore(4, 1, 4, device_rows=4)
+    table = store.init_device_table()
+    table, _ = store.prepare(table, np.arange(4))
+    warm = store.stats()["migration_bytes"]
+    for row in [0, 1, 0, 3, 2, 1]:
+        table, _ = store.prepare(table, np.asarray([row]))
+    store.flush_writebacks()
+    assert store.counters.evictions == 0
+    assert store.stats()["migration_bytes"] == warm
     store.close()
 
 
